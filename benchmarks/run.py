@@ -63,9 +63,18 @@ def main(argv=None) -> None:
     _emit(sections, "adaptive_compare_rounds_and_time", ad_rows)
 
     # sharded engines across simulated device counts (subprocesses: the
-    # device count is locked at jax init, so each count re-inits jax)
-    sh_rows, sh_records = sharded_bench.sharded_compare(quick=quick)
-    _emit(sections, "sharded_compare_1d_2d_vs_single", sh_rows)
+    # device count is locked at jax init, so each count re-inits jax).
+    # CPU only: on an accelerator this process holds the device the
+    # children would need
+    sh_records = []
+    if jax.default_backend() == "cpu":
+        sh_rows, sh_records = sharded_bench.sharded_compare(quick=quick)
+        _emit(sections, "sharded_compare_1d_2d_vs_single", sh_rows)
+    else:
+        print(f"sharded_compare refused on the {jax.default_backend()!r} "
+              "backend: its fake-device subprocesses need the device this "
+              "process holds (use chip_smoke.py --four-chips on real "
+              "chips)", file=sys.stderr)
 
     # edge-update churn: incremental patch vs full rebuild per batch, cache
     # retention under selective invalidation — gated like solve regressions
@@ -133,4 +142,6 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

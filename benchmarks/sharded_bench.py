@@ -86,7 +86,19 @@ def _records_for_this_process(quick: bool, batches) -> list[dict]:
 
 
 def sharded_compare(quick: bool = False, device_counts=None):
-    """Returns (csv_rows, json_records); spawns one subprocess per count."""
+    """Returns (csv_rows, json_records); spawns one subprocess per count.
+
+    CPU only: each child simulates its mesh with fake host devices. On an
+    accelerator the calling process already holds the chip, and a child
+    that needs it fails or hangs, so any other backend is refused. A child
+    that fails fails the comparison."""
+    import jax
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"sharded_compare simulates meshes in CPU subprocesses; on the "
+            f"{jax.default_backend()!r} backend this process holds the "
+            f"device its children would need. Run the sharded engines on "
+            f"real chips with `chip_smoke.py --four-chips` instead.")
     if device_counts is None:
         device_counts = (8,) if quick else (2, 4, 8)
     here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -103,9 +115,9 @@ def sharded_compare(quick: bool = False, device_counts=None):
         proc = subprocess.run(cmd, capture_output=True, text=True, env=env,
                               cwd=here, timeout=1200)
         if proc.returncode != 0:
-            print(f"sharded_bench subprocess ({n_dev} devices) failed:\n"
-                  f"{proc.stderr}", file=sys.stderr)
-            continue
+            raise RuntimeError(f"sharded_bench subprocess ({n_dev} devices) "
+                               f"failed with exit code {proc.returncode}:\n"
+                               f"{proc.stderr}")
         records.extend(json.loads(proc.stdout.strip().splitlines()[-1]))
     rows = [("n_dev", "family", "n", "m", "B", "engine", "us_per_solve",
              "speedup_vs_coo")]

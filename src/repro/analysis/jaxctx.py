@@ -16,8 +16,8 @@ Detection is static and name-based (no imports are resolved):
     jit-wrapped name);
   * control-flow bodies: any function NAME passed as an argument to
     ``lax.scan`` / ``while_loop`` / ``fori_loop`` / ``cond`` / ``switch`` /
-    ``map`` / ``shard_map`` / ``shard_map_compat`` / ``checkpoint`` /
-    ``remat`` / ``vmap`` / ``pmap`` / ``grad`` — conservative: a function
+    ``map`` / ``shard_map`` / ``checkpoint`` / ``remat`` / ``vmap`` /
+    ``pmap`` / ``grad`` — conservative: a function
     handed to a jax combinator is assumed traced;
   * contract methods: names listed in `traced_methods` (the engine
     protocol) defined inside a class body;
@@ -36,7 +36,7 @@ __all__ = ["JaxContext", "analyze", "TRACING_COMBINATORS"]
 # callables that trace a function argument when handed one by name
 TRACING_COMBINATORS = frozenset({
     "scan", "while_loop", "fori_loop", "cond", "switch", "map",
-    "shard_map", "shard_map_compat", "jit", "checkpoint", "remat",
+    "shard_map", "jit", "checkpoint", "remat",
     "vmap", "pmap", "grad", "value_and_grad", "custom_jvp", "custom_vjp",
 })
 

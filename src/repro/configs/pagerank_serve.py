@@ -113,8 +113,13 @@ def serve_config(smoke: bool = False) -> PPRServeConfig:
     return smoke_config() if smoke else full_config()
 
 
-def make_service(cfg: PPRServeConfig):
-    """Registry with every configured graph warm + the service over it."""
+def make_service(cfg: PPRServeConfig, graphs=None):
+    """Registry with every configured graph warm + the service over it.
+
+    graphs: optional prebuilt {registry name: Graph} registered INSTEAD of
+    generating `cfg.graphs` (a caller serving one large generated graph
+    through several services builds it once).
+    """
     import math
     from repro.serve.graph_registry import GraphRegistry
     from repro.serve.pagerank_service import PageRankService, ServeMetrics
@@ -129,8 +134,11 @@ def make_service(cfg: PPRServeConfig):
                         tune_cache=cfg.tune_cache,
                         tune_budget_s=cfg.tune_budget_s,
                         tune_require_cached=cfg.tune_require_cached)
-    for name, dataset, scale in cfg.graphs:
-        reg.register(name, generators.paper_dataset(dataset, scale))
+    if graphs is None:
+        graphs = {name: generators.paper_dataset(dataset, scale)
+                  for name, dataset, scale in cfg.graphs}
+    for name, g in graphs.items():
+        reg.register(name, g)
     tenants = [TenantSpec(name=n, priority=p,
                           deadline_s=math.inf if d is None else float(d),
                           max_depth=md)

@@ -49,6 +49,7 @@ import hashlib
 import math
 import os
 import time
+import warnings
 from pathlib import Path
 
 import jax
@@ -338,6 +339,12 @@ def _time_round(eng, x, t, acc, reps: int) -> float:
     return best
 
 
+def _describe(e: BaseException, limit: int = 300) -> str:
+    """'ExcType: message', clipped — what a store entry records about a
+    failure."""
+    return f"{type(e).__name__}: {e}"[:limit]
+
+
 def _cost_summary(eng, x, t, acc) -> dict | None:
     """flops / bytes-accessed of the compiled round where the backend
     exposes cost analysis (the launch/dryrun.py lower+compile scaffolding);
@@ -471,9 +478,13 @@ class Autotuner:
         try:
             measured, engines, skipped = self._measure_candidates(
                 g, key, heuristic, n_dev, build_kw)
-        except Exception:
+        except Exception as e:
             # a failed measurement pass must never take selection down
-            # with it: the zero-cost tier is always available
+            # with it: the zero-cost tier is always available — but the
+            # failure is reported, never mistaken for a slow candidate
+            warnings.warn(f"autotune measurement pass for {graph_name!r} "
+                          f"failed ({_describe(e)}); using the heuristic's "
+                          f"{heuristic!r}", RuntimeWarning, stacklevel=2)
             self.record("fallback_heuristic", graph_name, heuristic)
             return TuneDecision(mode=heuristic, source="fallback_heuristic",
                                 key=ks, heuristic=heuristic)
@@ -541,18 +552,21 @@ class Autotuner:
     def _measure_candidates(self, g: Graph, key: WorkloadKey, heuristic: str,
                             n_dev: int, build_kw: dict):
         """Build + time each shortlisted candidate within the budget.
-        Returns ({mode: seconds}, {mode: engine}, [skipped modes])."""
+        Returns ({mode: seconds}, {mode: engine}, {skipped mode: reason}):
+        the reason is "budget", or the exception type and message of a
+        candidate whose build or compile failed (also warned about — a
+        kernel the compiler refused must not pass for a slow one)."""
         block = build_kw.get("block", 128)
         cands = self._shortlist(g, key, heuristic, n_dev, block)
         B = min(key.batch, 128)   # bounded sample: bucket width, capped
         p = np.full((g.n, B), 1.0 / max(g.n, 1), np.float32)
         measured: dict[str, float] = {}
         engines: dict[str, object] = {}
-        skipped: list[str] = []
+        skipped: dict[str, str] = {}
         t0 = time.perf_counter()
         for mode in cands:
             if measured and time.perf_counter() - t0 > self.budget_s:
-                skipped.append(mode)
+                skipped[mode] = "budget"
                 continue
             try:
                 eng = select_engine(g, batch=key.batch, mode=mode,
@@ -562,8 +576,11 @@ class Autotuner:
                 acc = 0.5 * x
                 measured[mode] = _time_round(eng, x, t, acc, self.reps)
                 engines[mode] = eng
-            except Exception:
-                skipped.append(mode)   # infeasible in practice: disqualify
+            except Exception as e:
+                skipped[mode] = _describe(e)   # disqualified, with the cause
+                warnings.warn(f"autotune candidate {mode!r} failed: "
+                              f"{skipped[mode]}", RuntimeWarning,
+                              stacklevel=2)
         return measured, engines, skipped
 
 

@@ -82,7 +82,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.distributed import (put_partition_1d, put_partition_2d,
                                     spmv_1d_shard, spmv_2d_shard)
-from repro.distributed.sharding import shard_map_compat
 from repro.graph.ops import DeviceGraph, device_graph, spmm, spmv
 from repro.graph.partition import (col_layout_perm, partition_1d,
                                    partition_2d)
@@ -404,15 +403,21 @@ class BlockEllEngine:
         self.interpret = interpret
         self.fill_rate = fill_rate     # informational; not a pytree aux
 
+    # stable-shape slot padding rounds S up to a multiple of this. The
+    # values tensor is the engine's dominant allocation (4.3 GB at S=8 on
+    # the 1.04e6-vertex mesh), so a power-of-two step (one extra tile:
+    # 8 -> 16) would double it and outgrow a 16 GB chip next to the solve's
+    # working set; a step of 4 adds at most 3 slots per growth.
+    SLOT_QUANTUM = 4
+
     @classmethod
     def from_block_ell(cls, be: BlockEll, use_kernel: bool | None = None,
                        interpret: bool | None = None,
-                       pad_slots_to_pow2: bool = False) -> "BlockEllEngine":
+                       pad_slots: bool = False) -> "BlockEllEngine":
         block_cols, values = be.block_cols, be.values
-        if pad_slots_to_pow2:
-            s = 1
-            while s < be.slots:
-                s *= 2
+        if pad_slots:
+            q = cls.SLOT_QUANTUM
+            s = -(-be.slots // q) * q
             if s > be.slots:
                 # extra slots point at the diagonal with zero values: harmless
                 # by construction, and the padded S keeps jit shapes stable
@@ -438,12 +443,12 @@ class BlockEllEngine:
     def from_graph(cls, g: Graph, block: int = 128, reorder: bool = True,
                    use_kernel: bool | None = None,
                    interpret: bool | None = None,
-                   pad_slots_to_pow2: bool = False,
+                   pad_slots: bool = False,
                    perm=None) -> "BlockEllEngine":
         return cls.from_block_ell(build_block_ell(g, block=block,
                                                   reorder=reorder, perm=perm),
                                   use_kernel=use_kernel, interpret=interpret,
-                                  pad_slots_to_pow2=pad_slots_to_pow2)
+                                  pad_slots=pad_slots)
 
     @property
     def n(self) -> int:
@@ -495,7 +500,7 @@ class BlockEllEngine:
         return type(self).from_graph(g, block=self.block,
                                      use_kernel=self.use_kernel,
                                      interpret=self.interpret,
-                                     pad_slots_to_pow2=stable_shapes,
+                                     pad_slots=stable_shapes,
                                      perm=perm)
 
     def tree_flatten(self):
@@ -626,7 +631,7 @@ class Sharded1DEngine(ShardedEngine):
                                  axis_name=self.axes, rows=self.rows_per_dev,
                                  comm_dtype=self.comm_dtype)
 
-        fn = shard_map_compat(
+        fn = jax.shard_map(
             body, mesh=self.mesh,
             in_specs=(vec_spec, edge_spec, edge_spec, edge_spec),
             out_specs=vec_spec)
@@ -755,7 +760,7 @@ class Sharded2DEngine(ShardedEngine):
         # construction (the final all_gather along it makes every row group
         # identical), but the varying-axis type system can't prove that
         # through psum_scatter.
-        fn = shard_map_compat(
+        fn = jax.shard_map(
             body, mesh=self.mesh,
             in_specs=(vec_spec, edge_spec, edge_spec, edge_spec),
             out_specs=vec_spec, check_vma=False)
@@ -914,8 +919,8 @@ def select_engine(g: Graph, batch: int | None = None, mode: str = "auto", *,
     tiling round-trip).
     dg: reuse an existing DeviceGraph for the COO path (the serving registry
     passes its padded, shape-stable device graph).
-    stable_shapes: pad the ELL slot count to a power of two so edge updates
-    rarely change jit shapes.
+    stable_shapes: pad the ELL slot count to a multiple of
+    `BlockEllEngine.SLOT_QUANTUM` so edge updates rarely change jit shapes.
     mesh / grid / lane / comm_dtype: sharded-engine knobs — an explicit mesh
     to run on (default: all devices), the (R, C) grid for sharded_2d, the
     partition padding lane, and an optional wire dtype for the all-gather.
@@ -965,7 +970,7 @@ def select_engine(g: Graph, batch: int | None = None, mode: str = "auto", *,
         cls = BlockEllEngine if mode == "block_ell" else FusedBlockEllEngine
         return cls.from_graph(g, block=block, use_kernel=use_kernel,
                               interpret=interpret,
-                              pad_slots_to_pow2=stable_shapes, perm=perm)
+                              pad_slots=stable_shapes, perm=perm)
     if mode == "sharded_1d":
         return Sharded1DEngine.from_graph(g, mesh=mesh, lane=lane,
                                           dtype=dtype, comm_dtype=comm_dtype)

@@ -22,19 +22,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma=True):
-    """jax.shard_map across jax versions: new jax exposes it at top level
-    with `check_vma`; older jax has jax.experimental.shard_map with
-    `check_rep` (same meaning)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as sm_old
-    return sm_old(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check_vma)
-
-
 def flat_axes(multi_pod: bool):
     """All mesh axes, for flattened node/edge sharding (GNN/pagerank)."""
     return ("pod", "data", "model") if multi_pod else ("data", "model")
@@ -45,19 +32,9 @@ def batch_axes(multi_pod: bool):
 
 
 def _current_mesh():
-    get_am = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_am is not None:  # jax >= 0.5; older jax only has the concrete mesh
-        am = get_am()
-        if am is not None and not am.empty:
-            return am
-    try:  # concrete `with mesh:` context (not surfaced by get_abstract_mesh)
-        from jax._src import mesh as mesh_lib
-        pm = mesh_lib.thread_resources.env.physical_mesh
-        if pm is not None and not pm.empty:
-            return pm
-    except Exception:  # noqa: BLE001 — internal API moved; treat as no mesh
-        pass
-    return None
+    """The mesh set by `jax.set_mesh`, or None outside one."""
+    am = jax.sharding.get_abstract_mesh()
+    return None if am.empty else am
 
 
 def maybe_shard(x, *spec):

@@ -24,11 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-try:
-    _SMEM = pltpu.MemorySpace.SMEM
-except AttributeError:  # jax < 0.5 names it TPUMemorySpace
-    _SMEM = pltpu.TPUMemorySpace.SMEM
-
 
 def _kernel(y_ref, t_ref, acc_ref, ck_ref, t_out_ref, acc_out_ref):
     t_next = 2.0 * y_ref[...] - t_ref[...]
@@ -52,7 +47,7 @@ def cheb_step_pallas(y: jax.Array, t: jax.Array, acc: jax.Array,
         _kernel,
         grid=grid,
         in_specs=[spec, spec, spec,
-                  pl.BlockSpec(memory_space=_SMEM)],
+                  pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=[spec, spec],
         out_shape=[jax.ShapeDtypeStruct((r, lanes), jnp.float32),
                    jax.ShapeDtypeStruct((r, lanes), jnp.float32)],

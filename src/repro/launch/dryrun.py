@@ -49,7 +49,7 @@ def _compile_plan(plan, mesh):
     if step is None:  # shard_map paths need the mesh (cpaa-pagerank)
         step = plan.static["step_builder"](mesh)
     sharded_args = _attach_shardings(plan.abstract_args, plan.in_specs, mesh)
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = step.lower(*sharded_args)
         compiled = lowered.compile()
         cost = compiled.cost_analysis()

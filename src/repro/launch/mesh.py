@@ -17,10 +17,9 @@ ICI_BW = 50e9                 # bytes/s per link
 
 
 def mesh_kwargs(n_axes: int) -> dict:
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:  # jax < 0.5 has no explicit-sharding axis types
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+    """Auto (GSPMD-propagated) sharding on every axis: the plans in
+    `distributed.sharding` place arrays by constraint, not by type."""
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
 
 
 def make_production_mesh(*, multi_pod: bool = False):

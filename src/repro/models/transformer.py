@@ -19,7 +19,6 @@ import jax.numpy as jnp
 
 from repro.models.layers import (apply_rope, chunked_attention, decode_attention,
                                  dense_init, embed_init, rms_norm)
-from repro.distributed.sharding import shard_map_compat
 from repro.models.moe import MoEConfig, moe_apply, moe_capacity, moe_init
 
 
@@ -208,7 +207,7 @@ def _moe_a2a_sharded(mp, x, cfg: TransformerConfig):
         aux = jax.lax.pmean(aux, axes) if dp else jax.lax.pmean(aux, "model")
         return y.reshape(bl, sl, dl), aux
 
-    y, aux = shard_map_compat(
+    y, aux = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(xspec, P(None, None), P("model", None, None),
                   P("model", None, None), P("model", None, None)),

@@ -28,7 +28,8 @@ API at a cost of one attribute lookup per call.
 
 `profiled(logdir)` is the deep-dive hook: it wraps a region in
 `jax.profiler.trace` when a logdir is given (view with TensorBoard or
-Perfetto), and is a free no-op otherwise.
+Perfetto), raising if the profiler cannot start, and is a free no-op
+otherwise.
 
 This module (any function) and the service `_harvest` are the ONLY
 sanctioned blocking-fence points: `repro.analysis`'s JL006 rule flags
@@ -191,18 +192,13 @@ class Tracer:
 def profiled(logdir: str | None):
     """Opt-in deep-dive: wrap a region in `jax.profiler.trace(logdir)`.
 
-    No-op when logdir is falsy or the profiler is unavailable (some
-    backends build without it) — serving must never die because profiling
-    is broken.
+    No-op when logdir is falsy. When a logdir IS given, a profiler that
+    cannot start raises: a run asked to produce a trace must not quietly
+    produce none.
     """
     if not logdir:
         yield
         return
-    try:
-        import jax
-        ctx = jax.profiler.trace(logdir)
-    except Exception:
-        yield
-        return
-    with ctx:
+    import jax
+    with jax.profiler.trace(logdir):
         yield

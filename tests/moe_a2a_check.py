@@ -14,7 +14,6 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
-from repro.distributed.sharding import shard_map_compat  # noqa: E402
 from repro.launch.mesh import mesh_kwargs  # noqa: E402
 from repro.models.moe import MoEConfig, moe_apply, moe_apply_a2a, moe_init  # noqa: E402
 
@@ -35,7 +34,7 @@ def main():
         y, aux = moe_apply_a2a(p, xl, cfg, ep=4, axis_name="model")
         return y, jax.lax.pmean(jax.lax.pmean(aux, "model"), "data")
 
-    y_a2a, aux_a2a = jax.jit(shard_map_compat(
+    y_a2a, aux_a2a = jax.jit(jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P("data", None), P(None, None), P("model", None, None),
                   P("model", None, None), P("model", None, None)),
@@ -50,7 +49,7 @@ def main():
 
     # gradients flow through the a2a path
     def loss(w1):
-        y, _ = jax.jit(shard_map_compat(
+        y, _ = jax.jit(jax.shard_map(
             fn, mesh=mesh,
             in_specs=(P("data", None), P(None, None), P("model", None, None),
                       P("model", None, None), P("model", None, None)),

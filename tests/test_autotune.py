@@ -207,9 +207,32 @@ class TestAutotuner:
         monkeypatch.setattr(Autotuner, "_measure_candidates",
                             lambda *a, **k: (_ for _ in ()).throw(
                                 RuntimeError("boom")))
-        dec = tuner.tune(small_graph(), 8)
+        with pytest.warns(RuntimeWarning, match="RuntimeError: boom"):
+            dec = tuner.tune(small_graph(), 8)
         assert dec.source == "fallback_heuristic"
         assert dec.mode == heuristic_mode(small_graph(), 8)
+
+    def test_failed_candidate_records_its_error(self, tmp_path,
+                                                monkeypatch):
+        """A candidate whose build/compile raises is disqualified with the
+        exception recorded and warned about, never dropped as if slow."""
+        import repro.core.autotune as at
+        real = at.select_engine
+
+        def refuse_hub_tail(g, batch=None, mode="auto", **kw):
+            if mode == "hub_tail":
+                raise RuntimeError("RESOURCE_EXHAUSTED: refused")
+            return real(g, batch, mode, **kw)
+
+        monkeypatch.setattr(at, "select_engine", refuse_hub_tail)
+        tuner = Autotuner(TuningStore(tmp_path / "t.json"), budget_s=60.0)
+        with pytest.warns(RuntimeWarning, match="'hub_tail' failed"):
+            dec = tuner.tune(skewed_graph(), 8)
+        entry = tuner.store.get(dec.key)
+        assert dec.mode != "hub_tail"
+        assert entry["skipped"]["hub_tail"] == \
+            "RuntimeError: RESOURCE_EXHAUSTED: refused"
+        assert "hub_tail" not in entry["candidates"]
 
     def test_shortlist_gates_by_size_and_devices(self):
         tuner = Autotuner.__new__(Autotuner)

@@ -29,6 +29,21 @@ class TestBsrSpmm:
         np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_r),
                                    rtol=1e-5, atol=1e-5)
 
+    @pytest.mark.parametrize("max_prefetch", [1, 7, 24, 1 << 16])
+    def test_split_prefetch_vs_ref(self, max_prefetch):
+        """Row blocks split over several calls (one per SMEM-sized slice of
+        the flat slot index) give the single-call answer."""
+        from repro.kernels.bsr_spmm.bsr_spmm import bsr_spmm_pallas
+        g = generators.erdos_renyi(300, 5.0, seed=max_prefetch)
+        be = build_block_ell(g, block=32)
+        x = jax.random.normal(jax.random.PRNGKey(4), (be.n, 8), jnp.float32)
+        cols, vals = jnp.asarray(be.block_cols), jnp.asarray(be.values)
+        y_k = bsr_spmm_pallas(cols, vals, x, interpret=True,
+                              max_prefetch=max_prefetch)
+        np.testing.assert_allclose(np.asarray(y_k),
+                                   np.asarray(bsr_spmm_ref(cols, vals, x)),
+                                   rtol=1e-5, atol=1e-5)
+
     def test_vector_input_squeeze(self):
         g = generators.tri_mesh(8, 9)
         be = build_block_ell(g, block=16)

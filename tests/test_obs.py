@@ -198,6 +198,18 @@ class TestTrace:
         with profiled(None):
             pass                                # must be a free no-op
 
+    def test_profiled_raises_when_profiler_cannot_start(self, monkeypatch,
+                                                        tmp_path):
+        import jax
+
+        def broken(logdir):
+            raise RuntimeError("profiler unavailable")
+
+        monkeypatch.setattr(jax.profiler, "trace", broken)
+        with pytest.raises(RuntimeError, match="profiler unavailable"):
+            with profiled(str(tmp_path)):
+                pass
+
 
 # ---------------------------------------------------------------------------
 # convergence telemetry

@@ -1,5 +1,6 @@
 """Online PPR query service: batching correctness, cache, epochs, top-k."""
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -321,6 +322,12 @@ class TestRetraceGate:
 
         g = generators.tri_mesh(13, 17)
         svc = make_service(g, max_batch=8)
+        # jit caches and the trace log are process-wide: another test file
+        # on this worker may already have compiled (and logged) this
+        # graph's bucket-2 solve
+        from repro.core.engine import reset_apply_counts
+        jax.clear_caches()
+        reset_apply_counts()
         svc.query("g", seeds=(0,), top_k=5)      # warm bucket 1 only
         with pytest.raises(RetraceError) as ei:
             with retrace_gate():
