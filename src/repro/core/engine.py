@@ -340,12 +340,17 @@ class HubTailEngine:
         # sentinel row n: panel padding gathers exactly 0.0
         zero = jnp.zeros((1,) + x.shape[1:], xd.dtype)
         xd = jnp.concatenate([xd, zero])
-        y = jax.ops.segment_sum(xd[self.tail_src], self.tail_dst,
-                                num_segments=self.n_orig)
-        part = xd[self.panel_cols].sum(axis=1)
-        hub_y = jax.ops.segment_sum(part, self.panel_hub,
-                                    num_segments=self.n_hubs)
-        return y.at[self.hub_ids].add(hub_y)
+        with jax.named_scope("spmm.gather"):
+            tail = xd[self.tail_src]
+        with jax.named_scope("spmm.scatter"):
+            y = jax.ops.segment_sum(tail, self.tail_dst,
+                                    num_segments=self.n_orig)
+        with jax.named_scope("spmm.gather"):
+            part = xd[self.panel_cols].sum(axis=1)
+        with jax.named_scope("spmm.scatter"):
+            hub_y = jax.ops.segment_sum(part, self.panel_hub,
+                                        num_segments=self.n_hubs)
+            return y.at[self.hub_ids].add(hub_y)
 
     def cheb_round(self, y, t, acc, ck):
         return _default_cheb_round(y, t, acc, ck)

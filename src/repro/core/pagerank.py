@@ -230,10 +230,11 @@ def cpaa_adaptive_fixed(dg, p: jax.Array, c, tol, max_rounds: int,
             acc = jnp.where(upd, acc_next, acc)
             ck = jnp.where(run, ck_next, ck)
             k = k + run.astype(jnp.int32)
-        norm = colnorm(acc)
-        resid = jnp.sum(jnp.abs(norm - snap), axis=0)
-        col_rounds = jnp.where(active, k, col_rounds)
-        active = jnp.logical_and(active, resid > tol)
+        with jax.named_scope("cheb.residual"):
+            norm = colnorm(acc)
+            resid = jnp.sum(jnp.abs(norm - snap), axis=0)
+            col_rounds = jnp.where(active, k, col_rounds)
+            active = jnp.logical_and(active, resid > tol)
         return (t_prev, t_cur, acc, norm, ck, k, active, col_rounds, resid)
 
     (_, _, acc, _, _, k, _, col_rounds, resid) = jax.lax.while_loop(

@@ -496,11 +496,13 @@ def _transition_matmul(dg: DeviceGraph, x: jax.Array) -> jax.Array:
     """Shared spmv/spmm body: y[dst] += w[e] * x[src] over the edge list.
     Weights may be stored packed (bf16); they upcast to the solve dtype at
     multiply time so the segment_sum accumulates at full precision."""
-    w = dg.w if dg.w is not None else dg.inv_deg[dg.src]
-    if w.dtype != x.dtype:
-        w = w.astype(x.dtype)
-    contrib = x[dg.src] * (w if x.ndim == 1 else w[:, None])
-    return jax.ops.segment_sum(contrib, dg.dst, num_segments=dg.n)
+    with jax.named_scope("spmm.gather"):
+        w = dg.w if dg.w is not None else dg.inv_deg[dg.src]
+        if w.dtype != x.dtype:
+            w = w.astype(x.dtype)
+        contrib = x[dg.src] * (w if x.ndim == 1 else w[:, None])
+    with jax.named_scope("spmm.scatter"):
+        return jax.ops.segment_sum(contrib, dg.dst, num_segments=dg.n)
 
 
 def spmv(dg: DeviceGraph, x: jax.Array) -> jax.Array:

@@ -8,8 +8,10 @@ Four layers, each usable on its own:
                         relative bucket width at O(1) memory per bucket.
   * `obs.trace`       — lightweight query-lifecycle spans (submit, queue,
                         batch-form, solve dispatch, fenced device time,
-                        materialize) plus the opt-in `profiled()` hook that
-                        wraps a region in `jax.profiler.trace`.
+                        materialize), the tick-stage timer `stage()` that
+                        puts each stage on the profiler's clock too, and
+                        the opt-in `profiled()` hook that wraps a region
+                        in `jax.profiler.trace`.
   * `obs.convergence` — per-tick solver telemetry: adaptive `rounds_used`
                         vs the Formula 8 a-priori bound, residual-at-exit,
                         per-column converged fractions, and update-path
@@ -33,7 +35,7 @@ _EXPORTS = {
     "Family": "metrics", "MetricsRegistry": "metrics",
     "NULL_REGISTRY": "metrics",
     "Span": "trace", "Trace": "trace", "Tracer": "trace",
-    "NULL_TRACE": "trace", "profiled": "trace",
+    "NULL_TRACE": "trace", "stage": "trace", "profiled": "trace",
     "ConvergenceLog": "convergence", "TickTelemetry": "convergence",
     "UpdateTelemetry": "convergence",
     "MetricsServer": "export", "render_summary": "export",

@@ -253,8 +253,8 @@ def render_summary(snap: dict) -> str:
                      "mean=%.1fus" % (lat["p50"] * 1e6, lat["p99"] * 1e6,
                                       lat["p999"] * 1e6, lat["mean"] * 1e6))
     stage_bits = []
-    for stage in ("queue", "batch_form", "solve_dispatch", "solve_device",
-                  "materialize"):
+    for stage in ("queue", "schedule", "batch_form", "solve_dispatch",
+                  "upload", "solve_device", "materialize", "telemetry"):
         h = _merged_hist(snap, "serve_stage_seconds", stage=stage)
         if h["count"]:
             stage_bits.append("%s=%.1fus" % (stage, h["mean"] * 1e6))

@@ -26,6 +26,13 @@ long-running service can always answer "show me the last N queries" without
 growing. A disabled tracer hands out `NULL_TRACE`, which absorbs the whole
 API at a cost of one attribute lookup per call.
 
+`stage(span, hist, clock)` times one stage of a service tick on two clocks
+at once: a `jax.profiler.TraceAnnotation` named `span` (on the device
+trace's clock whenever a profiler trace is being taken, a no-op marker
+otherwise) and one observation of the stage histogram `hist` on the
+service's own (injectable) clock. Every tick stage is timed through it, so
+the histogram and the profiler see the same extent.
+
 `profiled(logdir)` is the deep-dive hook: it wraps a region in
 `jax.profiler.trace` when a logdir is given (view with TensorBoard or
 Perfetto), raising if the profiler cannot start, and is a free no-op
@@ -44,7 +51,8 @@ from collections import deque
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 
-__all__ = ["Span", "Trace", "Tracer", "NULL_TRACE", "profiled"]
+__all__ = ["Span", "Trace", "Tracer", "NULL_TRACE", "Stamp", "stage",
+           "profiled"]
 
 
 @dataclass
@@ -105,11 +113,6 @@ class Trace:
         """Record a zero-width event (e.g. "submit")."""
         now = time.perf_counter()
         self.spans.append(Span(name=name, start=now, end=now, kind=kind))
-
-    def duration(self, name: str) -> float:
-        """Total closed duration of all spans named `name`."""
-        return sum(s.duration for s in self.spans
-                   if s.name == name and s.closed)
 
     def span_names(self) -> list[str]:
         return [s.name for s in self.spans]
@@ -186,6 +189,32 @@ class Tracer:
         out = list(self.finished)
         self.finished.clear()
         return out
+
+
+class Stamp:
+    """Start and end of one timed stage, on the clock that timed it."""
+
+    __slots__ = ("start", "end")
+
+    def __init__(self, start: float):
+        self.start = start
+        self.end = start
+
+
+@contextmanager
+def stage(span: str, hist, clock=time.perf_counter):
+    """Time one stage: a profiler span `span` around one observation of
+    `hist` (a histogram instrument) on `clock`.
+
+    Yields a `Stamp`; its `end` is set when the block exits. A block that
+    raises closes the span and observes nothing.
+    """
+    from jax.profiler import TraceAnnotation
+    with TraceAnnotation(span):
+        st = Stamp(clock())
+        yield st
+        st.end = clock()
+    hist.observe(st.end - st.start)
 
 
 @contextmanager

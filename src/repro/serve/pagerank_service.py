@@ -87,6 +87,7 @@ from repro.core.pagerank import cpaa_adaptive_fixed, cpaa_fixed, power_refine
 from repro.obs import (ConvergenceLog, MetricsRegistry, NULL_REGISTRY,
                        TickTelemetry, Tracer, UpdateTelemetry)
 from repro.obs import export as obs_export
+from repro.obs.trace import stage as obs_stage
 from repro.serve.graph_registry import GraphRegistry
 from repro.serve.result_cache import ResultCache
 from repro.serve.scheduler import (AdmissionRejected, DeadlineScheduler,
@@ -226,6 +227,10 @@ class ServeMetrics:
         self.padded = r.counter(
             "serve_padded_columns_total",
             "pad columns solved (bucket width minus live columns)")
+        self.h2d_bytes = r.counter(
+            "serve_h2d_bytes_total",
+            "bytes of the host arrays ticks hand to the device (the [n, "
+            "bucket] float32 personalization)")
         self.updates = r.counter(
             "serve_updates_total", "edge-update batches by effective path",
             ("graph", "kind"))
@@ -308,7 +313,8 @@ def _solve_topk(engine, coeffs: jax.Array, p: jax.Array, rounds: int, k: int):
     `engine` is the registry's per-(graph, epoch) solve engine; it owns any
     vertex reordering internally, so top-k ids are original vertex ids."""
     pi, _ = cpaa_fixed(engine, coeffs, p, rounds=rounds)
-    scores, idx = jax.lax.top_k(pi.T, k)
+    with jax.named_scope("topk"):
+        scores, idx = jax.lax.top_k(pi.T, k)
     return idx.astype(jnp.int32), scores
 
 
@@ -320,7 +326,8 @@ def _refine_topk(engine, x0: jax.Array, p: jax.Array, c, rounds: int, k: int):
     of a cold CPAA solve (the Chebyshev series cannot be resumed; the power
     recurrence contracts from any warm start)."""
     pi = power_refine(engine, x0, p, c, rounds)
-    scores, idx = jax.lax.top_k(pi, k)
+    with jax.named_scope("topk"):
+        scores, idx = jax.lax.top_k(pi, k)
     return idx.astype(jnp.int32), scores
 
 
@@ -336,7 +343,8 @@ def _solve_topk_adaptive(engine, p: jax.Array, c, tol, max_rounds: int,
     and the per-column residual at exit."""
     pi, rounds_used, col_rounds, resid = cpaa_adaptive_fixed(
         engine, p, c, tol, max_rounds=max_rounds, chunk=chunk)
-    scores, idx = jax.lax.top_k(pi.T, k)
+    with jax.named_scope("topk"):
+        scores, idx = jax.lax.top_k(pi.T, k)
     return idx.astype(jnp.int32), scores, rounds_used, col_rounds, resid
 
 
@@ -861,15 +869,23 @@ class PageRankService:
         out: list[PPRResult] = []
         rec = None
         if self.scheduler.depth():
-            group = self.scheduler.next_group(now, force=force)
-            if group is None:
-                m.held.inc()
-            else:
-                m.ticks.inc()
-                self._tick_no += 1
-                m.queue_depth.set(self.scheduler.depth())
-                hits, rec = self._form_and_dispatch(group, now)
-                out.extend(hits)
+            with self._stage("schedule", "schedule"):
+                group = self.scheduler.next_group(now, force=force)
+                if group is None:
+                    m.held.inc()
+                else:
+                    m.ticks.inc()
+                    self._tick_no += 1
+                    m.queue_depth.set(self.scheduler.depth())
+                    rg = self.registry.get(group[0].q.graph)
+                    live = self._twin_hits(group, rg.epoch, out)
+                    if live and self._inflight:
+                        # the device is still solving the previous batch
+                        # while this batch forms: the overlap the async
+                        # tier buys
+                        m.overlap.inc()
+            if group is not None and live:
+                rec = self._form_and_dispatch(live, rg)
         if rec is not None:
             if self.async_dispatch:
                 self._inflight.append(rec)
@@ -885,22 +901,19 @@ class PageRankService:
             self._results[r.qid] = r
         return out
 
-    def _form_and_dispatch(self, group: list[QueueEntry], now: float
-                           ) -> tuple[list[PPRResult], _InFlight | None]:
-        """Batch formation + device dispatch for one released group.
+    def _stage(self, span: str, label: str):
+        """Time one tick stage: the profiler span `serve.<span>` and the
+        `serve_stage_seconds{stage=label}` histogram, on the service
+        clock."""
+        return obs_stage(f"serve.{span}", self.metrics.stage.labels(
+            stage=label), self._clock)
 
-        Returns: (twin cache-hit results resolved here, the in-flight
-        record of the dispatched solve — None when every query of the
-        group was answered from cache). The returned record is UNFENCED:
-        the caller decides when to `_harvest` it (that is the async
-        overlap point).
-        """
+    def _twin_hits(self, group: list[QueueEntry], epoch: int,
+                   out: list[PPRResult]) -> list[QueueEntry]:
+        """Answer from cache the queries of a released group whose twin
+        filled the cache since submission (appended to `out`); returns the
+        rest, which need a solve."""
         m = self.metrics
-        graph = group[0].q.graph
-        rg = self.registry.get(graph)
-        epoch = rg.epoch
-        out: list[PPRResult] = []
-
         # a twin query may have populated the cache since submission — that
         # is this query's disposition: a cache hit, counted here and only
         # here (its submit counted nothing)
@@ -919,43 +932,50 @@ class PageRankService:
                 out.append(self._materialize(e.q, epoch, *hit, cached=True))
             else:
                 live.append(e)
-        if not live:
-            return out, None
+        return live
+
+    def _form_and_dispatch(self, live: list[QueueEntry], rg) -> _InFlight:
+        """Batch formation + device dispatch for the queries of a released
+        group that need a solve (`live`, all on graph entry `rg`).
+
+        Returns the in-flight record of the dispatched solve. It is
+        UNFENCED: the caller decides when to `_harvest` it (that is the
+        async overlap point).
+        """
+        m = self.metrics
+        graph = live[0].q.graph
+        epoch = rg.epoch
 
         # ---- batch formation: identical in-flight queries share a column
-        if self._inflight:
-            # the device is still solving the previous batch while this
-            # host-side formation runs: the overlap the async tier buys
-            m.overlap.inc()
-        t_stage = self._clock()
-        for e in live:
-            queued = e.tr.end("queue")
-            m.stage.labels(stage="queue").observe(
-                queued if queued else t_stage - e.t0)
-            e.tr.begin("batch_form")
-        cols: dict[tuple, int] = {}     # cache key -> column index
-        col_of: list[int] = []          # per live query
-        reps: list[PPRQuery] = []       # representative query per column
-        for e in live:
-            key = e.q.key(epoch)
-            j = cols.get(key)
-            if j is None:
-                j = len(reps)
-                cols[key] = j
-                reps.append(e.q)
-            col_of.append(j)
+        with self._stage("batch_form", "batch_form") as st:
+            for e in live:
+                queued = e.tr.end("queue")
+                m.stage.labels(stage="queue").observe(
+                    queued if queued else st.start - e.t0)
+                e.tr.begin("batch_form")
+            cols: dict[tuple, int] = {}     # cache key -> column index
+            col_of: list[int] = []          # per live query
+            reps: list[PPRQuery] = []       # representative query per column
+            for e in live:
+                key = e.q.key(epoch)
+                j = cols.get(key)
+                if j is None:
+                    j = len(reps)
+                    cols[key] = j
+                    reps.append(e.q)
+                col_of.append(j)
 
-        sched, coeffs = self.registry.schedule(live[0].q.c, live[0].q.tol)
-        n = rg.n
-        b_pad = self._bucket(len(reps))
-        m.padded.inc(b_pad - len(reps))
-        p = np.zeros((n, b_pad), np.float32)
-        for j, q in enumerate(reps):
-            p[np.asarray(q.seeds, np.int64), j] = 1.0  # canonical at birth
-        p[:, len(reps):] = 1.0  # pad columns: uniform mass, discarded
-        for e in live:
-            e.tr.end("batch_form")
-        m.stage.labels(stage="batch_form").observe(self._clock() - t_stage)
+            sched, coeffs = self.registry.schedule(live[0].q.c,
+                                                   live[0].q.tol)
+            n = rg.n
+            b_pad = self._bucket(len(reps))
+            m.padded.inc(b_pad - len(reps))
+            p = np.zeros((n, b_pad), np.float32)
+            for j, q in enumerate(reps):
+                p[np.asarray(q.seeds, np.int64), j] = 1.0  # canonical at birth
+            p[:, len(reps):] = 1.0  # pad columns: uniform mass, discarded
+            for e in live:
+                e.tr.end("batch_form")
 
         # dispatch-time slack telemetry: how much budget the most urgent
         # rider had left, net of the expected solve (deadline health)
@@ -965,37 +985,37 @@ class PageRankService:
             m.slack.labels(graph=graph).observe(
                 min(deadlines) - self._clock() - est)
 
-        # ---- dispatch (host): trace/compile + enqueue on the device
-        # stream. JAX dispatch is asynchronous — the jitted call returns
-        # with unfenced arrays; the device fence is _harvest's job.
+        # ---- dispatch (host): upload, trace/compile + enqueue on the
+        # device stream. JAX dispatch is asynchronous — the jitted call
+        # returns with unfenced arrays; the device fence is _harvest's job.
         k = min(self.max_top_k, n)
         mode = "adaptive" if self.adaptive else "fixed"
-        t_stage = self._clock()
-        for e in live:
-            e.tr.begin("solve_dispatch")
-        used = resid = None
-        tol_eff, c_eff = float(live[0].q.tol), float(live[0].q.c)
-        if self.adaptive:
-            plan = self.registry.adaptive_schedule(live[0].q.c, live[0].q.tol,
-                                                   chunk=self.adaptive_chunk)
-            idx, scores, used, _, resid = _solve_topk_adaptive(
-                rg.engine, jnp.asarray(p), plan.c, plan.tol,
-                max_rounds=plan.max_rounds, chunk=plan.chunk, k=k)
-            tol_eff, c_eff = plan.tol, plan.c
-        else:
-            idx, scores = _solve_topk(rg.engine, coeffs, jnp.asarray(p),
-                                      rounds=sched.rounds, k=k)
-        for e in live:
-            e.tr.end("solve_dispatch")
-        m.stage.labels(stage="solve_dispatch").observe(
-            self._clock() - t_stage)
-        rec = _InFlight(graph=graph, epoch=epoch, rg=rg, live=live,
-                        cols=cols, col_of=col_of, n_reps=len(reps),
-                        b_pad=b_pad, k=k, mode=mode,
-                        rounds_bound=sched.rounds, tol=tol_eff, c=c_eff,
-                        idx=idx, scores=scores, used=used, resid=resid,
-                        t_dispatch0=t_stage)
-        return out, rec
+        with self._stage("dispatch", "solve_dispatch") as dispatch:
+            for e in live:
+                e.tr.begin("solve_dispatch")
+            with self._stage("upload", "upload"):
+                p_dev = jnp.asarray(p)
+            m.h2d_bytes.inc(p.nbytes)
+            used = resid = None
+            tol_eff, c_eff = float(live[0].q.tol), float(live[0].q.c)
+            if self.adaptive:
+                plan = self.registry.adaptive_schedule(
+                    live[0].q.c, live[0].q.tol, chunk=self.adaptive_chunk)
+                idx, scores, used, _, resid = _solve_topk_adaptive(
+                    rg.engine, p_dev, plan.c, plan.tol,
+                    max_rounds=plan.max_rounds, chunk=plan.chunk, k=k)
+                tol_eff, c_eff = plan.tol, plan.c
+            else:
+                idx, scores = _solve_topk(rg.engine, coeffs, p_dev,
+                                          rounds=sched.rounds, k=k)
+            for e in live:
+                e.tr.end("solve_dispatch")
+        return _InFlight(graph=graph, epoch=epoch, rg=rg, live=live,
+                         cols=cols, col_of=col_of, n_reps=len(reps),
+                         b_pad=b_pad, k=k, mode=mode,
+                         rounds_bound=sched.rounds, tol=tol_eff, c=c_eff,
+                         idx=idx, scores=scores, used=used, resid=resid,
+                         t_dispatch0=dispatch.start)
 
     def _harvest(self, rec: _InFlight) -> list[PPRResult]:
         """Fence one in-flight batch and materialize its results.
@@ -1015,74 +1035,74 @@ class PageRankService:
         # JL006's allowlist (repro.analysis LintConfig.blocking_allowed)
         # names this function; a blocking call anywhere else in the serve
         # path is a lint error, not a judgment call.
-        t_stage = self._clock()
-        for e in rec.live:
-            e.tr.begin("solve_device", kind="device")
-        jax.block_until_ready(rec.scores)
-        t_ready = self._clock()
-        for e in rec.live:
-            e.tr.end("solve_device")
-        m.stage.labels(stage="solve_device").observe(t_ready - t_stage)
-
-        # the EWMA the deadline scheduler plans with: dispatch-to-ready,
-        # i.e. what a batch riding this (graph, bucket) should expect
-        t_solve = t_ready - rec.t_dispatch0
-        self.estimator.observe(graph, rec.b_pad, t_solve)
-        m.solve_seconds.labels(graph=graph, bucket=rec.b_pad).observe(t_solve)
-        m.solve_ewma.labels(graph=graph, bucket=rec.b_pad).set(
-            self.estimator.estimate(graph, rec.b_pad))
-
-        rounds_used = int(rec.used) if rec.used is not None \
-            else rec.rounds_bound
-        engine_name = type(rec.rg.engine).__name__
-        m.solves.labels(graph=graph, engine=engine_name, bucket=rec.b_pad,
-                        mode=rec.mode).inc()
-        m.rounds_used.labels(graph=graph, mode=rec.mode).inc(rounds_used)
-        m.rounds_bound.labels(graph=graph, mode=rec.mode).inc(
-            rec.rounds_bound)
+        with self._stage("fence", "solve_device") as fence:
+            for e in rec.live:
+                e.tr.begin("solve_device", kind="device")
+            jax.block_until_ready(rec.scores)
+            for e in rec.live:
+                e.tr.end("solve_device")
 
         # ---- materialize: host copies, cache fills, per-query results
         out: list[PPRResult] = []
-        t_stage = self._clock()
-        for e in rec.live:
-            e.tr.begin("materialize")
-        idx = np.asarray(rec.idx)
-        scores = np.asarray(rec.scores)
-        for key, j in rec.cols.items():
-            self.cache.put(key, (idx[j], scores[j]))
-        for i, e in enumerate(rec.live):
-            # disposition: served by this solve (twins included — each
-            # query counts itself, the COLUMNS were deduplicated)
-            self.cache.count_miss()
-            m.served.labels(graph=e.q.graph, disposition="solved").inc()
-            j = rec.col_of[i]
-            out.append(self._materialize(e.q, epoch, idx[j], scores[j],
-                                         cached=False,
-                                         batch_size=rec.n_reps))
-            e.tr.end("materialize")
-            done = self._clock()
-            m.latency.labels(graph=e.q.graph, disposition="solved").observe(
-                done - e.t0)
-            if done > e.deadline:
-                m.deadline_miss.labels(graph=e.q.graph,
-                                       tenant=e.tenant).inc()
-            m.tracer.finish(e.tr)
-        m.stage.labels(stage="materialize").observe(self._clock() - t_stage)
+        with self._stage("materialize", "materialize"):
+            for e in rec.live:
+                e.tr.begin("materialize")
+            idx = np.asarray(rec.idx)
+            scores = np.asarray(rec.scores)
+            for key, j in rec.cols.items():
+                self.cache.put(key, (idx[j], scores[j]))
+            for i, e in enumerate(rec.live):
+                # disposition: served by this solve (twins included — each
+                # query counts itself, the COLUMNS were deduplicated)
+                self.cache.count_miss()
+                m.served.labels(graph=e.q.graph, disposition="solved").inc()
+                j = rec.col_of[i]
+                out.append(self._materialize(e.q, epoch, idx[j], scores[j],
+                                             cached=False,
+                                             batch_size=rec.n_reps))
+                e.tr.end("materialize")
+                done = self._clock()
+                m.latency.labels(graph=e.q.graph,
+                                 disposition="solved").observe(done - e.t0)
+                if done > e.deadline:
+                    m.deadline_miss.labels(graph=e.q.graph,
+                                           tenant=e.tenant).inc()
+                m.tracer.finish(e.tr)
 
-        # ---- convergence telemetry: the paper's bound, checked per tick
-        if rec.resid is not None:
-            r_live = np.asarray(rec.resid)[:rec.n_reps]
-            residual = float(r_live.max()) if r_live.size else 0.0
-            converged = float(np.mean(r_live <= rec.tol)) if r_live.size \
-                else 1.0
-        else:
-            residual = 0.0      # fixed path: no residual is measured
-            converged = 1.0     # by construction of the a-priori bound
-        m.convergence.record_tick(TickTelemetry(
-            tick=self._tick_no, graph=graph, engine=engine_name,
-            bucket=rec.b_pad, columns=rec.n_reps, rounds_used=rounds_used,
-            rounds_bound=rec.rounds_bound, residual=residual,
-            converged_frac=converged, tol=rec.tol, c=rec.c))
+        with self._stage("telemetry", "telemetry"):
+            # the EWMA the deadline scheduler plans with: dispatch-to-ready,
+            # i.e. what a batch riding this (graph, bucket) should expect
+            t_solve = fence.end - rec.t_dispatch0
+            self.estimator.observe(graph, rec.b_pad, t_solve)
+            m.solve_seconds.labels(graph=graph, bucket=rec.b_pad).observe(
+                t_solve)
+            m.solve_ewma.labels(graph=graph, bucket=rec.b_pad).set(
+                self.estimator.estimate(graph, rec.b_pad))
+
+            rounds_used = int(rec.used) if rec.used is not None \
+                else rec.rounds_bound
+            engine_name = type(rec.rg.engine).__name__
+            m.solves.labels(graph=graph, engine=engine_name, bucket=rec.b_pad,
+                            mode=rec.mode).inc()
+            m.rounds_used.labels(graph=graph, mode=rec.mode).inc(rounds_used)
+            m.rounds_bound.labels(graph=graph, mode=rec.mode).inc(
+                rec.rounds_bound)
+
+            # ---- convergence telemetry: the paper's bound, checked per tick
+            if rec.resid is not None:
+                r_live = np.asarray(rec.resid)[:rec.n_reps]
+                residual = float(r_live.max()) if r_live.size else 0.0
+                converged = float(np.mean(r_live <= rec.tol)) \
+                    if r_live.size else 1.0
+            else:
+                residual = 0.0      # fixed path: no residual is measured
+                converged = 1.0     # by construction of the a-priori bound
+            m.convergence.record_tick(TickTelemetry(
+                tick=self._tick_no, graph=graph, engine=engine_name,
+                bucket=rec.b_pad, columns=rec.n_reps,
+                rounds_used=rounds_used, rounds_bound=rec.rounds_bound,
+                residual=residual, converged_frac=converged, tol=rec.tol,
+                c=rec.c))
         return out
 
     def _flush_inflight(self) -> None:

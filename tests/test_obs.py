@@ -16,7 +16,7 @@ from repro.obs.convergence import (ConvergenceLog, TickTelemetry,
 from repro.obs.export import (MetricsServer, SNAPSHOT_SCHEMA, snapshot,
                               to_prometheus, validate_snapshot)
 from repro.obs.metrics import (Histogram, MetricsRegistry, NULL_REGISTRY)
-from repro.obs.trace import NULL_TRACE, Trace, Tracer, profiled
+from repro.obs.trace import NULL_TRACE, Trace, Tracer, profiled, stage
 from repro.serve import (GraphRegistry, PageRankService, PPRQuery,
                          ServeMetrics)
 
@@ -210,6 +210,40 @@ class TestTrace:
             with profiled(str(tmp_path)):
                 pass
 
+    def test_stage_observes_on_the_given_clock(self):
+        ticks = iter([10.0, 10.25])
+        h = Histogram()
+        with stage("serve.test", h, clock=lambda: next(ticks)) as st:
+            assert st.start == 10.0
+        assert st.end == 10.25
+        assert h.count == 1 and h.sum == pytest.approx(0.25)
+
+    def test_stage_that_raises_observes_nothing(self):
+        h = Histogram()
+        with pytest.raises(ValueError):
+            with stage("serve.test", h):
+                raise ValueError("boom")
+        assert h.count == 0
+
+    def test_stage_opens_a_profiler_span(self, monkeypatch):
+        import jax
+        opened = []
+
+        class Annotation:
+            def __init__(self, name):
+                opened.append(name)
+
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                opened.append("closed")
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+        with stage("serve.batch_form", Histogram()):
+            pass
+        assert opened == ["serve.batch_form", "closed"]
+
 
 # ---------------------------------------------------------------------------
 # convergence telemetry
@@ -358,6 +392,34 @@ class TestServeInstrumentation:
                       "materialize"):
             assert svc.metrics.stage.labels(stage=stage).count == 1
         assert svc.metrics.stage.labels(stage="queue").count == 4
+
+    def test_new_stage_labels_observed_once_per_tick(self):
+        g = generators.tri_mesh(9, 11)
+        svc = make_service(g, max_batch=4)
+        for i in range(10):
+            svc.submit(PPRQuery(qid=i, graph="g", seeds=(i,)))
+        svc.run_until_drained()
+        ticks = svc.metrics.ticks.total()
+        assert ticks == 3
+        for stage_ in ("schedule", "upload", "telemetry", "batch_form",
+                       "solve_dispatch", "solve_device", "materialize"):
+            assert svc.metrics.stage.labels(stage=stage_).count == ticks
+
+    def test_h2d_bytes_count_the_personalization_per_tick(self):
+        g = generators.tri_mesh(9, 11)
+        svc = make_service(g, max_batch=8)
+        h2d = svc.metrics.h2d_bytes
+        seen = []
+        for i, n_queries in enumerate((8, 3, 1)):   # buckets 8, 4, 1
+            for j in range(n_queries):
+                svc.submit(PPRQuery(qid=100 * i + j, graph="g",
+                                    seeds=(10 * i + j,)))
+            before = h2d.total()
+            svc.tick()
+            seen.append(h2d.total() - before)
+        assert seen == [g.n * b * 4 for b in (8, 4, 1)]
+        assert svc.metrics.registry.get("serve_h2d_bytes_total").total() \
+            == sum(seen)
 
     def test_rounds_bound_never_exceeded_adaptive(self):
         """Acceptance: Formula 8 stays a hard cap under adaptive serving."""

@@ -17,15 +17,21 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+import re
+
 from repro.core.chebyshev import default_chunk, make_schedule
-from repro.core.engine import FusedBlockEllEngine, HubTailEngine
+from repro.core.engine import CooEngine, FusedBlockEllEngine, HubTailEngine
+from repro.core.pagerank import cpaa_fixed
+from repro.graph.ops import DeviceGraph
 from repro.kernels.bsr_spmm.bsr_spmm import MAX_PREFETCH_SLOTS, bsr_spmm_pallas
 from repro.kernels.cheb_step.cheb_step import cheb_step_pallas
 from repro.serve.pagerank_service import _solve_topk_adaptive
 
 # mesh-1m = paper_dataset("NACA0015", 10.0): n = 1,040,000 = 8125 row
-# blocks of 128, at most 8 tiles per row block after the BFS reorder
+# blocks of 128, at most 8 tiles per row block after the BFS reorder; the
+# benchmark's 1040 x 1000 triangulated grid has 6,231,842 directed edges
 MESH_N, MESH_RB, MESH_S = 1_040_000, 8125, 8
+MESH_M = 6_231_842
 # chunglu-1m's hub/tail split at the default hub bar (deg >= 32, panels
 # of 32 columns): hubs, tail edges and hub panels
 CL_N, CL_HUBS, CL_TAIL, CL_PANELS = 1_000_000, 44_148, 4_364_154, 299_850
@@ -126,3 +132,32 @@ def test_serve_solve_compiles(spec, build, kernels):
         max_rounds=make_schedule(c, tol).rounds,
         chunk=default_chunk(c, tol), k=32).compile()
     assert (_kernel_calls(compiled) > 0) == kernels
+
+
+def test_spmm_scopes_reach_the_compiled_program(spec):
+    """The global solve at mesh-1m's size on COO: every scatter-add and
+    every gather the chip's compiler emits keeps its named scope in the
+    op metadata, which the profiler's op events carry."""
+    i32, f32 = jnp.int32, jnp.float32
+    dg = DeviceGraph.tree_unflatten(MESH_N, (
+        spec((MESH_M,), i32), spec((MESH_M,), i32), spec((MESH_N,), f32),
+        spec((MESH_M,), f32)))
+    sched = make_schedule(0.85, 1e-6)
+    text = cpaa_fixed.lower(
+        CooEngine(dg), spec((sched.rounds + 1,), f32), spec((MESH_N,), f32),
+        rounds=sched.rounds).compile().as_text()
+    ops = {}
+    op_name = re.compile(
+        r'= \S+ (gather|scatter-add|fusion)\(.*op_name="([^"]*)"')
+    for line in text.splitlines():
+        m = op_name.search(line)
+        if m:
+            ops.setdefault(m.group(1), []).append(m.group(2))
+    fusions = ops.get("fusion", [])
+    assert any(p.endswith("spmm.scatter/scatter-add") for p in fusions)
+    assert any(p.endswith("spmm.gather/gather") for p in fusions)
+    for p in fusions:
+        if p.endswith("/scatter-add"):
+            assert "/spmm.scatter/" in p, p
+        if p.endswith("/gather"):
+            assert "/spmm.gather/" in p, p
