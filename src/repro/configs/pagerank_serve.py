@@ -31,7 +31,7 @@ class PPRServeConfig:
     # solve-engine format: "auto" (device-count + degree-skew + fill-rate
     # heuristic), "tuned" (measured selection via core/autotune: the
     # workload-bucketed tuning store, measure-on-miss), "coo", "hub-tail",
-    # "block_ell", "fused", "sharded-1d" or "sharded-2d" — see
+    # "ell", "block_ell", "fused", "sharded-1d" or "sharded-2d" — see
     # core/engine.select_engine and docs/performance.md
     engine: str = "auto"
     # tuned mode only: tuning-store path (None = $REPRO_TUNE_CACHE or

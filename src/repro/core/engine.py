@@ -17,6 +17,12 @@ not an algorithm choice, so it lives behind an `Engine`:
                          factored form (xd = x * inv_deg once per round), so
                          no per-edge weights are stored at all: ~4 bytes/edge
                          on the hub side vs COO's 12.
+  * EllEngine          — scatter-free neighbour table for bounded-degree
+                         graphs: each vertex's adjacency list packed into a
+                         [K, n] int32 table (K = the largest degree), applied
+                         as gathers of xd = x * inv_deg summed over the K
+                         slots. The graph is undirected, so in-neighbours are
+                         the adjacency list and no segment_sum follows.
   * BlockEllEngine     — the block-ELL Pallas SpMM (`kernels/bsr_spmm`):
                          vertices BFS-reordered so edges cluster into BxB
                          tiles, each tile a dense matmul on the MXU. The
@@ -57,10 +63,11 @@ Every engine also implements `refresh(g, delta, *, dg=None, ...)` — the
 edge-update hook the serving registry calls instead of re-running
 `select_engine` (format choice is sticky across updates). COO is free: the
 registry patches the padded DeviceGraph in place and the engine, holding
-the same object, is already current. Block-ELL re-tiles but reuses its BFS
-perm when the delta's touched-vertex set is small (skipping the dominant
-host-side BFS); the sharded engines rebuild their partition on the SAME
-mesh. `delta` is a `graph.structure.EdgeDelta` (or None to force the
+the same object, is already current. ELL patches its own table in place
+from the delta and hands over to COO once the table outgrows its bar.
+Block-ELL re-tiles but reuses its BFS perm when the delta's touched-vertex
+set is small (skipping the dominant host-side BFS); the sharded engines
+rebuild their partition on the SAME mesh. `delta` is a `graph.structure.EdgeDelta` (or None to force the
 conservative rebuild).
 
 `select_engine(g, batch)` picks a format host-side: with multiple devices
@@ -69,11 +76,14 @@ and a graph big enough to amortize the per-round collectives it shards
 otherwise); on a single device it picks by tile fill-rate — block-ELL pays
 off when the BxB tiles are dense enough that the dense-tile flops beat the
 gather/scatter traffic of segment_sum (community and mesh-like graphs);
-scattered graphs (kmer chains, power-law hubs) stay on COO.
+below the fill bar, graphs whose neighbour table stays small next to the
+edge count (bounded degree: meshes) take the ELL gather, and the rest stay
+on COO.
 """
 from __future__ import annotations
 
 import math
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -82,7 +92,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.distributed import (put_partition_1d, put_partition_2d,
                                     spmv_1d_shard, spmv_2d_shard)
-from repro.graph.ops import DeviceGraph, device_graph, spmm, spmv
+from repro.graph.ops import (DeviceGraph, _pad_pow2, device_graph, spmm,
+                             spmv)
 from repro.graph.partition import (col_layout_perm, partition_1d,
                                    partition_2d)
 from repro.graph.structure import (BlockEll, Graph, block_fill_rate,
@@ -93,6 +104,7 @@ from repro.kernels.cheb_step.ops import cheb_step
 __all__ = [
     "CooEngine",
     "HubTailEngine",
+    "EllEngine",
     "BlockEllEngine",
     "FusedBlockEllEngine",
     "ShardedEngine",
@@ -108,8 +120,8 @@ __all__ = [
     "reset_apply_counts",
 ]
 
-ENGINE_MODES = ("auto", "tuned", "coo", "hub_tail", "block_ell", "fused",
-                "sharded_1d", "sharded_2d")
+ENGINE_MODES = ("auto", "tuned", "coo", "hub_tail", "ell", "block_ell",
+                "fused", "sharded_1d", "sharded_2d")
 
 # Per-engine-class apply() invocation counts. apply() runs at TRACE time
 # under jit, so in a jitted serving loop these count COMPILATIONS of the
@@ -154,6 +166,16 @@ def _default_cheb_round(y, t, acc, ck):
     the kernel engines override this to fuse the HBM traffic too)."""
     t_next = 2.0 * y - t
     return t_next, acc + ck * t_next
+
+
+def _scaled_with_sentinel(x: jax.Array, inv_deg: jax.Array) -> jax.Array:
+    """xd = x * inv_deg (packed storage upcast before the multiply) with one
+    zero row appended at index n: a table slot holding the sentinel id n
+    gathers exactly 0.0."""
+    inv = inv_deg if inv_deg.dtype == x.dtype else inv_deg.astype(x.dtype)
+    xd = x * (inv if x.ndim == 1 else inv[:, None])
+    zero = jnp.zeros((1,) + x.shape[1:], xd.dtype)
+    return jnp.concatenate([xd, zero])
 
 
 @jax.tree_util.register_pytree_node_class
@@ -333,13 +355,8 @@ class HubTailEngine:
 
     def apply(self, x: jax.Array) -> jax.Array:
         _count_apply(self.name, x)
-        inv = self.inv_deg
-        if inv.dtype != x.dtype:
-            inv = inv.astype(x.dtype)   # packed storage -> full-precision mul
-        xd = x * (inv if x.ndim == 1 else inv[:, None])
         # sentinel row n: panel padding gathers exactly 0.0
-        zero = jnp.zeros((1,) + x.shape[1:], xd.dtype)
-        xd = jnp.concatenate([xd, zero])
+        xd = _scaled_with_sentinel(x, self.inv_deg)
         with jax.named_scope("spmm.gather"):
             tail = xd[self.tail_src]
         with jax.named_scope("spmm.scatter"):
@@ -379,6 +396,238 @@ class HubTailEngine:
         n_orig, hub_min_deg, panel_width, acc_dtype = aux
         return cls(*children, n_orig=n_orig, hub_min_deg=hub_min_deg,
                    panel_width=panel_width, acc_dtype=jnp.dtype(acc_dtype))
+
+
+@partial(jax.jit, donate_argnums=(0, 1))
+def _ell_patch(nbr, inv_deg, ks, vs, vals, rows, inv_new):
+    """One fused scatter for an ELL table edit: the changed (slot, vertex)
+    cells and the touched rows of inv_deg. Both arrays are DONATED, so XLA
+    writes into the existing buffers; the caller replaces its references."""
+    return nbr.at[ks, vs].set(vals), inv_deg.at[rows].set(inv_new)
+
+
+@jax.tree_util.register_pytree_node_class
+class EllEngine:
+    """Scatter-free neighbour-table (ELLPACK) engine for bounded-degree graphs.
+
+    The graph is undirected, so vertex v's in-neighbours are its adjacency
+    list and y = P x reads row by row: y[v] = sum over u in N(v) of
+    x[u] / deg[u]. The lists are packed into a slot-major int32 table
+    `nbr` [K, n] of width K = the largest degree, padded with the sentinel
+    id n. A round is one O(n) scale xd = x * inv_deg, a zero row appended
+    for the sentinel, and a sum over the K slots of gathered rows of xd
+    (f32 accumulation): no scatter-add anywhere. Isolated vertices keep
+    their self-loop slot, so P stays column-stochastic, and padding gathers
+    exactly 0.0 (the mass invariant; the internal layout is the identity).
+
+    At B = 1 the sum reads one gather xd[nbr] of [K, n]; at B > 1 each slot
+    gathers its own [n, B] block and the blocks are added up, which skips
+    the one gather's extra [K, n, B] copy (PERF.md section 6 has the
+    layouts' times and temporaries).
+
+    Like HubTailEngine, no per-edge weights are stored: 4 bytes a slot
+    against COO's 12 an edge. `weight_dtype` packs inv_deg (bf16 halves
+    it; upcast before the multiply).
+
+    `refresh` edits the table in place from the edge delta: a host copy of
+    the table finds each removed neighbour's slot and each added one's
+    first free slot (O(batch * K) host work), and one donated scatter
+    rewrites those cells and the touched rows of inv_deg, so the solve's
+    compiled shapes stay. An insert into a full column widens the table by
+    a quarter (at least to the new largest degree): small degree changes
+    keep the shapes, and growth recompiles O(log K) times.
+    """
+
+    name = "ell"
+
+    # jaxlint: disable=JL004 -- live_slots is build/update bookkeeping, deliberately not pytree state
+    def __init__(self, inv_deg: jax.Array, nbr: jax.Array, n_orig: int,
+                 acc_dtype=jnp.float32, live_slots: int | None = None,
+                 host_nbr: np.ndarray | None = None):
+        self.inv_deg = inv_deg         # [n] weight_dtype (packed ok)
+        self.nbr = nbr                 # [K, n] int32, sentinel n = padding
+        self.n_orig = n_orig
+        self.acc_dtype = jnp.dtype(acc_dtype)
+        self.live_slots = live_slots   # occupied slots; not a pytree aux
+        # host copy of nbr that refresh patches (None: rebuild instead)
+        self._host_nbr = host_nbr
+
+    @classmethod
+    def from_graph(cls, g: Graph, dtype=jnp.float32, weight_dtype=None,
+                   min_width: int = 1) -> "EllEngine":
+        """Host-side build, O(m) after a stable sort by dst (a no-op pass on
+        `Graph.from_undirected_edges` output, which is already dst-sorted).
+        `min_width` floors K, so a rebuild keeps the table's jit shape when
+        an update does not raise the largest degree."""
+        from repro.graph.ops import check_int32_range
+        n = g.n
+        wdtype = jnp.dtype(dtype) if weight_dtype is None \
+            else jnp.dtype(weight_dtype)
+        src = np.asarray(g.src)
+        dst = np.asarray(g.dst)
+        if dst.size and np.any(dst[1:] < dst[:-1]):
+            order = np.argsort(dst, kind="stable")
+            src, dst = src[order], dst[order]
+        cnt = np.bincount(dst, minlength=n)
+        width = max(int(cnt.max(initial=0)), int(min_width), 1)
+        check_int32_range(n, n * width, what="EllEngine")
+        starts = np.cumsum(cnt) - cnt
+        slot = np.arange(dst.size) - starts[dst]
+        nbr = np.full((width, n), n, np.int32)    # n -> zero sentinel
+        nbr[slot, dst] = src
+        inv_deg = 1.0 / np.maximum(np.bincount(src, minlength=n), 1)
+        # in-place edits assume the `from_undirected_edges` contract (the
+        # one EdgeSlots checks): self loops unique and only on otherwise
+        # isolated vertices; any other graph refreshes by rebuilding
+        loop = src == dst
+        lv = src[loop]
+        patchable = np.unique(lv).size == lv.size and \
+            not np.isin(lv, src[~loop]).any()
+        # the device table is a private copy: jax's CPU backend zero-copies
+        # aligned numpy buffers, and refresh edits the host copy in place
+        return cls(inv_deg=jnp.asarray(inv_deg, wdtype),
+                   nbr=jnp.asarray(nbr.copy()), n_orig=n, acc_dtype=dtype,
+                   live_slots=int(dst.size),
+                   host_nbr=nbr if patchable else None)
+
+    @property
+    def n(self) -> int:
+        return self.n_orig
+
+    @property
+    def width(self) -> int:
+        return self.nbr.shape[0]
+
+    @property
+    def slot_fill(self) -> float | None:
+        """Live neighbour slots over n * K: the share of the table that is
+        real work (None on an engine rebuilt from pytree leaves)."""
+        if self.live_slots is None:
+            return None
+        return self.live_slots / max(self.width * self.n_orig, 1)
+
+    @property
+    def dtype(self):
+        return self.acc_dtype
+
+    @property
+    def weight_dtype(self):
+        return self.inv_deg.dtype
+
+    def to_internal(self, x: jax.Array) -> jax.Array:
+        return x
+
+    def from_internal(self, x: jax.Array) -> jax.Array:
+        return x
+
+    def apply(self, x: jax.Array) -> jax.Array:
+        _count_apply(self.name, x)
+        with jax.named_scope("spmm.gather"):
+            xd = _scaled_with_sentinel(x, self.inv_deg)
+            acc = jnp.promote_types(x.dtype, jnp.float32)
+            if x.ndim == 1:
+                y = xd[self.nbr].astype(acc).sum(axis=0)
+            else:
+                y = xd[self.nbr[0]].astype(acc)
+                for k in range(1, self.width):
+                    y = y + xd[self.nbr[k]].astype(acc)
+            return y.astype(x.dtype)
+
+    def cheb_round(self, y, t, acc, ck):
+        return _default_cheb_round(y, t, acc, ck)
+
+    def refresh(self, g: Graph | None, delta=None, *, dg=None,
+                **kw) -> "EllEngine | CooEngine":
+        """Apply an edge-update batch: patch the table in place from
+        `delta` (returns self), or, with no delta or no host copy, rebuild
+        from `g` without narrowing K (deletes keep the compiled shapes).
+        A table that no longer clears `auto`'s bar (n * K <=
+        ELL_MAX_SLOTS_PER_EDGE * m) hands the graph to a CooEngine on the
+        registry's patched DeviceGraph `dg` (built from `g` if None)."""
+        if delta is not None and self._host_nbr is not None:
+            self._patch(delta)
+            eng = self
+        elif g is not None:
+            eng = type(self).from_graph(g, dtype=self.acc_dtype,
+                                        weight_dtype=self.inv_deg.dtype,
+                                        min_width=self.width)
+        else:
+            raise ValueError("EllEngine.refresh needs the host graph: the "
+                             "engine holds no host table to patch")
+        if _ell_table_fits(eng.n_orig, eng.width, eng.live_slots):
+            return eng
+        return CooEngine(dg if dg is not None else
+                         device_graph(g, self.acc_dtype,
+                                      weight_dtype=self.inv_deg.dtype))
+
+    def _patch(self, delta) -> None:
+        """Edit the host table by an EdgeDelta and push the changed cells.
+
+        The table is the whole state: a touched vertex's degree is its
+        column's occupied slots less its self loop, if any. Removals go
+        first (deleted edges in both directions, and the self loop of a
+        vertex that gains its first edge), then additions (inserted edges,
+        and a self loop for a vertex left with none)."""
+        n, tab = self.n_orig, self._host_nbr
+        ins, dele, t = delta.inserted, delta.deleted, delta.touched
+        col = tab[:, t]
+        loop = (col == t).any(axis=0)
+        deg = (col != n).sum(axis=0) - loop
+        for keys, sign in ((dele, -1), (ins, 1)):
+            ends = np.concatenate([keys // n, keys % n])
+            deg = deg + sign * np.bincount(np.searchsorted(t, ends),
+                                           minlength=t.size)
+        rc = np.concatenate([dele % n, dele // n, t[loop & (deg > 0)]])
+        rv = np.concatenate([dele // n, dele % n, t[loop & (deg > 0)]])
+        hit = tab[:, rc] == rv
+        if not hit.any(axis=0).all():
+            raise RuntimeError("EllEngine host table out of step with the "
+                               "delta: a removed neighbour is absent")
+        rk = hit.argmax(axis=0)
+        tab[rk, rc] = n
+        ic = np.concatenate([ins % n, ins // n, t[~loop & (deg == 0)]])
+        iv = np.concatenate([ins // n, ins % n, t[~loop & (deg == 0)]])
+        order = np.argsort(ic, kind="stable")
+        ic, iv = ic[order], iv[order]
+        first = np.searchsorted(ic, ic, side="left")
+        rank = np.arange(ic.size) - first        # nth addition to a column
+        free = tab[:, ic] == n
+        width = tab.shape[0]
+        if (free.sum(axis=0) <= rank).any():
+            # a column overflows: widen by a quarter, at least to the need
+            need = int(((~free).sum(axis=0)
+                        + np.searchsorted(ic, ic, side="right") - first).max())
+            grown = max(need, -(-width * 5 // 4))
+            tab = np.concatenate(
+                [tab, np.full((grown - width, n), n, np.int32)])
+            self._host_nbr = tab
+            free = tab[:, ic] == n
+        nth = free & (np.cumsum(free, axis=0) == rank + 1)
+        ik = nth.argmax(axis=0)
+        tab[ik, ic] = iv
+        self.live_slots += ic.size - rc.size
+        inv = (1.0 / np.maximum(deg, 1)).astype(np.dtype(self.inv_deg.dtype))
+        cells = np.concatenate([rk, ik]) * n + np.concatenate([rc, ic])
+        if tab.shape[0] != width or cells.size * 64 >= tab.size:
+            # a new shape, or no longer a sliver of the table: upload it
+            self.nbr = jnp.asarray(tab.copy())
+            self.inv_deg = self.inv_deg.at[t].set(inv)
+            return
+        # a cell freed and refilled in one batch appears twice with its
+        # final value both times, so the duplicate writes are idempotent
+        cells, (vals,) = _pad_pow2(cells, [tab.ravel()[cells]])
+        rows, (inv,) = _pad_pow2(t, [inv], minimum=64)
+        self.nbr, self.inv_deg = _ell_patch(self.nbr, self.inv_deg,
+                                            cells // n, cells % n, vals,
+                                            rows, inv)
+
+    def tree_flatten(self):
+        return (self.inv_deg, self.nbr), (self.n_orig, str(self.acc_dtype))
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        n_orig, acc_dtype = aux
+        return cls(*children, n_orig=n_orig, acc_dtype=jnp.dtype(acc_dtype))
 
 
 @jax.tree_util.register_pytree_node_class
@@ -825,12 +1074,52 @@ SHARDED_MIN_N = 1 << 16
 HUB_TAIL_MIN_N = 1 << 17
 HUB_TAIL_MIN_EDGE_FRAC = 0.4
 
+# auto mode takes the ELL gather over COO's gather + scatter-add when the
+# neighbour table holds at most this many slots per directed edge
+# (n * max degree <= factor * m): the break-even of COO's cost per edge
+# against the ELL round's cost per slot, 15.4 ns against 6.7 ns (2.29) at
+# B = 1 on a TPU v5e, where the ratio is smallest (4.38 at B = 128).
+# Padding mesh-1m's table to K = 8 and 10 puts the break-even near
+# K = 13, 2.2 slots an edge, at both B = 1 and 128. The bar is re-checked
+# after every refresh.
+ELL_MAX_SLOTS_PER_EDGE = 2.25
+
+# A round at B columns holds the K gathered [n, B] blocks at once:
+# 4 * n * K * B bytes (3.20 GB at K = 6, B = 128 on mesh-1m, 4.26 at 8,
+# 5.33 at 10; COO's round holds 3.22), so up to 2.25x COO's temporaries
+# near the slot bar. auto keeps a graph on COO when they would take more
+# than this share of the device's memory.
+ELL_MAX_TEMP_SHARE = 0.5
+
 
 def _hub_edge_fraction(g: Graph, thr: int) -> float:
     """Fraction of directed edges whose destination has deg >= thr."""
     deg = g.deg
     m = max(int(g.m), 1)
     return float(deg[deg >= thr].sum()) / m
+
+
+def _ell_table_fits(n: int, k: int, m: int) -> bool:
+    """True iff a [k, n] neighbour table holds at most
+    ELL_MAX_SLOTS_PER_EDGE slots per directed edge (m of them)."""
+    return n * k <= ELL_MAX_SLOTS_PER_EDGE * m
+
+
+def _device_memory_bytes() -> int | None:
+    """The first device's memory limit; None where the backend reports
+    none (the CPU)."""
+    return (jax.devices()[0].memory_stats() or {}).get("bytes_limit")
+
+
+def _ell_fits(g: Graph, batch: int | None = None) -> bool:
+    """`_ell_table_fits` for g's table, K = its largest in-degree, and
+    its round's gathered blocks at `batch` columns within
+    ELL_MAX_TEMP_SHARE of the device's memory."""
+    k = int(np.bincount(g.dst, minlength=g.n).max(initial=0))
+    limit = _device_memory_bytes()
+    temp = 4 * g.n * k * max(batch or 1, 1)
+    return _ell_table_fits(g.n, k, g.m) and \
+        (limit is None or temp <= ELL_MAX_TEMP_SHARE * limit)
 
 
 def _auto_choice(g: Graph, batch: int | None = None, *, block: int = 128,
@@ -880,7 +1169,7 @@ def _auto_choice(g: Graph, batch: int | None = None, *, block: int = 128,
             probe_cache.put_fill(g, block, fill)
     threshold = _default_min_fill() if min_fill is None else min_fill
     if fill < threshold:
-        return "coo", None
+        return ("ell" if _ell_fits(g, batch) else "coo"), None
     return "fused", perm
 
 
@@ -905,8 +1194,8 @@ def select_engine(g: Graph, batch: int | None = None, mode: str = "auto", *,
                   weight_dtype=None, tuner=None, probe_cache=None):
     """Pick/build the solve engine for a graph (host-side, once per epoch).
 
-    mode: "coo" | "hub_tail" | "block_ell" | "fused" | "sharded_1d" |
-    "sharded_2d" force a format (dashes accepted: "hub-tail"); "auto" first
+    mode: "coo" | "hub_tail" | "ell" | "block_ell" | "fused" | "sharded_1d"
+    | "sharded_2d" force a format (dashes accepted: "hub-tail"); "auto" first
     checks the device axis — with >= 2 devices and g.n >= `sharded_min_n` it
     shards (a 2D grid when >= 4 devices and the graph is big enough to
     amortize the two-phase collectives, the paper-faithful 1D rows
@@ -914,7 +1203,9 @@ def select_engine(g: Graph, batch: int | None = None, mode: str = "auto", *,
     HUB_TAIL_MIN_N and hubs receiving >= HUB_TAIL_MIN_EDGE_FRAC of the
     edges) take the hub/tail split, and everything else falls to the
     fill-rate choice: block-ELL when its tile fill-rate clears `min_fill`
-    (dense-enough tiles to beat segment_sum), otherwise COO. "tuned"
+    (dense-enough tiles to beat segment_sum), otherwise the ELL gather when
+    the neighbour table is at most ELL_MAX_SLOTS_PER_EDGE slots per edge
+    (bounded degree), else COO. "tuned"
     replaces the guess with a measurement: the workload-bucketed autotuner
     (core.autotune) consults its persistent store and, on a miss, times
     the feasible candidates and picks the measured winner (tie-break
@@ -930,7 +1221,7 @@ def select_engine(g: Graph, batch: int | None = None, mode: str = "auto", *,
     to run on (default: all devices), the (R, C) grid for sharded_2d, the
     partition padding lane, and an optional wire dtype for the all-gather.
     weight_dtype: packed storage dtype for edge weights / inv_deg on the
-    COO and hub-tail paths (bf16 halves them; accumulation stays in
+    COO, hub-tail and ELL paths (bf16 halves them; accumulation stays in
     `dtype`). The tile/partition engines ignore it (f32 values).
     tuner: the `core.autotune.Autotuner` mode="tuned" consults (None = the
     process-wide default over `$REPRO_TUNE_CACHE`).
@@ -971,6 +1262,8 @@ def select_engine(g: Graph, batch: int | None = None, mode: str = "auto", *,
         return coo()
     if mode == "hub_tail":
         return hub_tail()
+    if mode == "ell":
+        return EllEngine.from_graph(g, dtype=dtype, weight_dtype=weight_dtype)
     if mode in ("block_ell", "fused"):
         cls = BlockEllEngine if mode == "block_ell" else FusedBlockEllEngine
         return cls.from_graph(g, block=block, use_kernel=use_kernel,

@@ -184,8 +184,9 @@ def main(argv=None):
     ap.add_argument("--updates", type=int, default=0,
                     help="edge-update batches interleaved (pagerank only)")
     ap.add_argument("--engine", default=None,
-                    choices=["auto", "tuned", "coo", "hub-tail", "block_ell",
-                             "fused", "sharded-1d", "sharded-2d"],
+                    choices=["auto", "tuned", "coo", "hub-tail", "ell",
+                             "block_ell", "fused", "sharded-1d",
+                             "sharded-2d"],
                     help="pagerank solve-engine override (default from "
                          "config); 'tuned' selects by measurement via the "
                          "persistent tuning store")

@@ -59,7 +59,7 @@ import jax.numpy as jnp
 from repro.core.autotune import (Autotuner, TuningStore, log2_bucket,
                                  process_probe_cache)
 from repro.core.chebyshev import ChebSchedule, default_chunk, make_schedule
-from repro.core.engine import CooEngine, select_engine
+from repro.core.engine import CooEngine, EllEngine, select_engine
 from repro.graph.ops import (DeviceGraph, EdgeSlots, device_graph,
                              patch_device_graph)
 from repro.graph.structure import EdgeDelta, Graph, edge_delta
@@ -199,6 +199,11 @@ class _RegistryObs:
             "graph_engine_info",
             "1 for the engine class currently serving the graph",
             ("graph", "engine"))
+        self.slot_fill = reg.gauge(
+            "graph_engine_slot_fill",
+            "live neighbour slots over n * K of the ELL engine's table "
+            "(0 once the graph has left the ELL engine)",
+            ("graph",))
 
     def set_graph_gauges(self, rg: "RegisteredGraph") -> None:
         self.epoch.labels(graph=rg.name).set(rg.epoch)
@@ -209,6 +214,12 @@ class _RegistryObs:
             if values[0] == rg.name:    # a rebuild may have switched class
                 inst.set(0.0)
         self.engine_info.labels(graph=rg.name, engine=current).set(1.0)
+        fill = getattr(rg.engine, "slot_fill", None)
+        if fill is not None:
+            self.slot_fill.labels(graph=rg.name).set(fill)
+        elif any(v[0] == rg.name for v, _ in self.slot_fill.children()):
+            # the table outgrew its bar and the graph moved to COO
+            self.slot_fill.labels(graph=rg.name).set(0.0)
 
 
 class GraphRegistry:
@@ -217,7 +228,7 @@ class GraphRegistry:
     Args:
         dtype: accumulation dtype of device graphs and solves.
         engine: engine selection mode for `select_engine` ("auto" picks
-            COO / hub-tail / block-ELL / sharded per graph shape; "tuned"
+            COO / hub-tail / ELL / block-ELL / sharded per graph shape; "tuned"
             consults the workload-bucketed autotuner — measured once per
             (graph, shape class), persisted in the tuning store).
         batch_hint: expected micro-batch width, steering auto selection.
@@ -415,9 +426,11 @@ class GraphRegistry:
         if patch is not None:
             patch_device_graph(rg.dg, patch)
             rg._host_stale = True   # materialized on next read, not per batch
-            if isinstance(rg.engine, CooEngine):
-                # the COO engine shares rg.dg, already patched in place —
-                # refresh without forcing the host Graph materialization
+            if isinstance(rg.engine, (CooEngine, EllEngine)):
+                # COO shares rg.dg, already patched in place; ELL edits its
+                # own table from the delta (and hands over to COO on rg.dg
+                # when the table outgrows its bar) — neither forces the
+                # host Graph materialization
                 rg.engine = rg.engine.refresh(None, delta, dg=rg.dg)
             else:
                 rg.engine = rg.engine.refresh(rg.host, delta, dg=rg.dg,

@@ -193,6 +193,17 @@ class TestAutotuner:
         assert dec.mode == heuristic_mode(g, 8)
         assert tuner.measured_count() == 0
 
+    def test_require_cached_miss_keeps_heuristic_ell_pick(self, tmp_path):
+        # bounded degree under the fill bar: the heuristic's ELL pick
+        # stands, though "ell" is never among the measured candidates
+        tuner = Autotuner(TuningStore(tmp_path / "absent.json"),
+                          require_cached=True)
+        g = generators.tri_mesh(40, 40)
+        dec = tuner.tune(g, 8)
+        assert dec.source == "fallback_heuristic"
+        assert dec.mode == heuristic_mode(g, 8) == "ell"
+        assert tuner.measured_count() == 0
+
     def test_require_cached_corrupt_store_falls_back(self, tmp_path):
         path = tmp_path / "t.json"
         path.write_text("{not json")

@@ -194,6 +194,7 @@ class TestSelection:
     def test_forced_modes(self):
         g = generators.tri_mesh(9, 11)
         assert select_engine(g, mode="coo").name == "coo"
+        assert select_engine(g, mode="ell").name == "ell"
         assert select_engine(g, mode="block_ell").name == "block_ell"
         assert select_engine(g, mode="fused").name == "block_ell_fused"
         with pytest.raises(ValueError):
@@ -204,8 +205,33 @@ class TestSelection:
         assert select_engine(dense, min_fill=0.05).name == "block_ell_fused"
 
     def test_auto_prefers_coo_on_scattered_graphs(self):
+        # fill < 1%, max degree 5 over 2.09 directed edges a vertex: the
+        # [5, n] table would be 2.39 slots an edge, past ELL's bar
         sparse = generators.kmer_chains(4_000, seed=0)  # fill < 1%
         assert select_engine(sparse, min_fill=0.05).name == "coo"
+
+    @pytest.mark.parametrize("make, expected", [
+        # power-law: one hub's degree sets every row's width
+        (lambda: generators.powerlaw_ba(4_000, 3, seed=0), "coo"),
+        # fill 1.5%, max degree 6 over 5.8: 1.03 slots an edge
+        (lambda: generators.tri_mesh(40, 40), "ell"),
+        (lambda: generators.kmer_chains(2_000, seed=1), "ell"),
+    ], ids=["powerlaw", "mesh", "kmer_short"])
+    def test_auto_under_fill_bar_picks_ell_or_coo(self, make, expected):
+        """Under the fill bar, bounded-degree graphs take the ELL gather;
+        graphs whose neighbour table would outgrow their edges stay COO."""
+        assert select_engine(make(), min_fill=0.05).name == expected
+
+    def test_auto_keeps_coo_when_ell_blocks_outgrow_memory(self,
+                                                          monkeypatch):
+        """The ELL round holds K gathered [n, B] blocks: past
+        ELL_MAX_TEMP_SHARE of the device's memory the graph stays COO."""
+        import repro.core.engine as engine_mod
+        g = generators.tri_mesh(40, 40)        # K = 6: 4.9 MB at B = 128
+        monkeypatch.setattr(engine_mod, "_device_memory_bytes",
+                            lambda: 8_000_000)
+        assert select_engine(g, batch=128, min_fill=0.05).name == "coo"
+        assert select_engine(g, batch=8, min_fill=0.05).name == "ell"
 
     def test_auto_small_graph_stays_coo(self):
         tiny = generators.tri_mesh(5, 5)
@@ -231,7 +257,7 @@ class TestServeIntegration:
         assert rg.engine is not eng0            # epoch bump rebuilds once
         assert rg.engine.name == "block_ell_fused"
 
-    @pytest.mark.parametrize("mode", ["coo", "block_ell", "fused"])
+    @pytest.mark.parametrize("mode", ["coo", "ell", "block_ell", "fused"])
     def test_service_answers_match_oracle_on_every_engine(self, mode):
         from repro.serve import GraphRegistry, PageRankService, PPRQuery
         g = generators.tri_mesh(8, 9)

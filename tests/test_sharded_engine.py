@@ -220,6 +220,28 @@ class TestShardedSelection:
         # fill-rate logic must be untouched even on a multi-device process
         assert select_engine(generators.tri_mesh(5, 5)).name == "coo"
 
+    def test_auto_below_threshold_takes_ell_on_bounded_degree(self):
+        # the same holds past the small-graph bar: a mesh under the fill
+        # bar takes the single-device ELL gather, not a sharded engine
+        assert select_engine(generators.tri_mesh(40, 40)).name == "ell"
+
+    @pytest.mark.parametrize("n_dev, axes, expected", [
+        (2, ("dev",), "sharded_1d"),
+        (4, ("row", "col"), "sharded_2d"),
+        (1, ("dev",), "ell"),
+    ])
+    def test_auto_shards_bounded_degree_graphs_by_device_count(
+            self, n_dev, axes, expected):
+        """The device axis is decided before the format: a mesh-like graph
+        that would take the ELL gather on one device shards on several.
+        `heuristic_mode` reads only the mesh's size and axis names, so a
+        stand-in decides here without that many devices."""
+        from types import SimpleNamespace
+        from repro.core.engine import heuristic_mode
+        mesh = SimpleNamespace(devices=np.empty(n_dev), axis_names=axes)
+        g = generators.tri_mesh(40, 40)      # n = 1600
+        assert heuristic_mode(g, mesh=mesh, sharded_min_n=256) == expected
+
     def test_auto_shards_large_graphs_on_multi_device(self):
         if jax.device_count() < 2:
             pytest.skip("needs >= 2 devices")

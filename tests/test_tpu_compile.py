@@ -20,7 +20,8 @@ from jax.sharding import SingleDeviceSharding
 import re
 
 from repro.core.chebyshev import default_chunk, make_schedule
-from repro.core.engine import CooEngine, FusedBlockEllEngine, HubTailEngine
+from repro.core.engine import (CooEngine, EllEngine, FusedBlockEllEngine,
+                               HubTailEngine)
 from repro.core.pagerank import cpaa_fixed
 from repro.graph.ops import DeviceGraph
 from repro.kernels.bsr_spmm.bsr_spmm import MAX_PREFETCH_SLOTS, bsr_spmm_pallas
@@ -32,6 +33,8 @@ from repro.serve.pagerank_service import _solve_topk_adaptive
 # benchmark's 1040 x 1000 triangulated grid has 6,231,842 directed edges
 MESH_N, MESH_RB, MESH_S = 1_040_000, 8125, 8
 MESH_M = 6_231_842
+# its neighbour table: maximum degree 6, so [6, n] int32 slots
+MESH_K = 6
 # chunglu-1m's hub/tail split at the default hub bar (deg >= 32, panels
 # of 32 columns): hubs, tail edges and hub panels
 CL_N, CL_HUBS, CL_TAIL, CL_PANELS = 1_000_000, 44_148, 4_364_154, 299_850
@@ -161,3 +164,35 @@ def test_spmm_scopes_reach_the_compiled_program(spec):
             assert "/spmm.scatter/" in p, p
         if p.endswith("/gather"):
             assert "/spmm.gather/" in p, p
+
+
+def _ell_engine(spec):
+    children = (spec((MESH_N,), jnp.float32),
+                spec((MESH_K, MESH_N), jnp.int32))
+    return EllEngine.tree_unflatten((MESH_N, "float32"), children)
+
+
+def _assert_gather_without_scatter(text):
+    assert re.search(r'op_name="[^"]*/spmm\.gather/', text)
+    assert not re.search(r"= \S+ scatter\(", text)
+
+
+def test_ell_global_solve_holds_no_scatter(spec):
+    """The global solve at mesh-1m's size on the neighbour table, B = 1:
+    the gather reaches the program under its scope and no scatter does."""
+    sched = make_schedule(0.85, 1e-6)
+    f32 = jnp.float32
+    text = cpaa_fixed.lower(
+        _ell_engine(spec), spec((sched.rounds + 1,), f32),
+        spec((MESH_N,), f32), rounds=sched.rounds).compile().as_text()
+    _assert_gather_without_scatter(text)
+
+
+def test_ell_serve_solve_holds_no_scatter(spec):
+    """The service's adaptive 128-column tick on the same table."""
+    c, tol = 0.85, 1e-4
+    text = _solve_topk_adaptive.lower(
+        _ell_engine(spec), spec((MESH_N, 128), jnp.float32), c, tol,
+        max_rounds=make_schedule(c, tol).rounds,
+        chunk=default_chunk(c, tol), k=32).compile().as_text()
+    _assert_gather_without_scatter(text)
