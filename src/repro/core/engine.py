@@ -261,10 +261,12 @@ class HubTailEngine:
     DEFAULT_MIN_DEG = 32    # hub bar: deg >= 32 captures ~2/3 of the edges
     DEFAULT_PANEL_WIDTH = 32  # columns per panel: pad waste vs reduce count
 
+    # jaxlint: disable=JL004 -- hub_edges is a build statistic, deliberately not pytree state
     def __init__(self, inv_deg: jax.Array, tail_src: jax.Array,
                  tail_dst: jax.Array, panel_cols: jax.Array,
                  panel_hub: jax.Array, hub_ids: jax.Array, n_orig: int,
-                 hub_min_deg: int, panel_width: int, acc_dtype=jnp.float32):
+                 hub_min_deg: int, panel_width: int, acc_dtype=jnp.float32,
+                 hub_edges: int | None = None):
         self.inv_deg = inv_deg         # [n] weight_dtype (packed ok)
         self.tail_src = tail_src       # [m_tail] int32
         self.tail_dst = tail_dst       # [m_tail] int32
@@ -275,6 +277,7 @@ class HubTailEngine:
         self.hub_min_deg = hub_min_deg
         self.panel_width = panel_width
         self.acc_dtype = jnp.dtype(acc_dtype)
+        self.hub_edges = hub_edges     # live panel slots; not a pytree aux
 
     @classmethod
     def from_graph(cls, g: Graph, hub_min_deg: int | None = None,
@@ -326,7 +329,7 @@ class HubTailEngine:
                    panel_hub=jnp.asarray(panel_hub),
                    hub_ids=jnp.asarray(hub_ids),
                    n_orig=n, hub_min_deg=thr, panel_width=width,
-                   acc_dtype=dtype)
+                   acc_dtype=dtype, hub_edges=int(hsrc.size))
 
     @property
     def n(self) -> int:
@@ -335,6 +338,23 @@ class HubTailEngine:
     @property
     def n_hubs(self) -> int:
         return self.hub_ids.shape[0]
+
+    @property
+    def hub_edge_share(self) -> float | None:
+        """Hub edges over all directed edges: the share of the round the
+        panels carry (None on an engine rebuilt from pytree leaves)."""
+        if self.hub_edges is None:
+            return None
+        return self.hub_edges / max(self.hub_edges + self.tail_src.shape[0],
+                                    1)
+
+    @property
+    def panel_fill(self) -> float | None:
+        """Hub edges over panel slots: the share of the panels that is real
+        work, the rest sentinel padding (None as above)."""
+        if self.hub_edges is None:
+            return None
+        return self.hub_edges / max(self.panel_cols.size, 1)
 
     @property
     def dtype(self):
@@ -357,17 +377,21 @@ class HubTailEngine:
         _count_apply(self.name, x)
         # sentinel row n: panel padding gathers exactly 0.0
         xd = _scaled_with_sentinel(x, self.inv_deg)
-        with jax.named_scope("spmm.gather"):
-            tail = xd[self.tail_src]
-        with jax.named_scope("spmm.scatter"):
-            y = jax.ops.segment_sum(tail, self.tail_dst,
-                                    num_segments=self.n_orig)
-        with jax.named_scope("spmm.gather"):
-            part = xd[self.panel_cols].sum(axis=1)
-        with jax.named_scope("spmm.scatter"):
-            hub_y = jax.ops.segment_sum(part, self.panel_hub,
-                                        num_segments=self.n_hubs)
-            return y.at[self.hub_ids].add(hub_y)
+        # the halves' scopes sit outside spmm.gather / spmm.scatter, so a
+        # trace reads each op by kernel and, one level up, by half
+        with jax.named_scope("hub_tail.tail"):
+            with jax.named_scope("spmm.gather"):
+                tail = xd[self.tail_src]
+            with jax.named_scope("spmm.scatter"):
+                y = jax.ops.segment_sum(tail, self.tail_dst,
+                                        num_segments=self.n_orig)
+        with jax.named_scope("hub_tail.panel"):
+            with jax.named_scope("spmm.gather"):
+                part = xd[self.panel_cols].sum(axis=1)
+            with jax.named_scope("spmm.scatter"):
+                hub_y = jax.ops.segment_sum(part, self.panel_hub,
+                                            num_segments=self.n_hubs)
+                return y.at[self.hub_ids].add(hub_y)
 
     def cheb_round(self, y, t, acc, ck):
         return _default_cheb_round(y, t, acc, ck)

@@ -204,6 +204,16 @@ class _RegistryObs:
             "live neighbour slots over n * K of the ELL engine's table "
             "(0 once the graph has left the ELL engine)",
             ("graph",))
+        self.hub_edge_share = reg.gauge(
+            "graph_engine_hub_edge_share",
+            "hub edges over all directed edges in the hub/tail engine's "
+            "split (0 once the graph has left the hub/tail engine)",
+            ("graph",))
+        self.panel_fill = reg.gauge(
+            "graph_engine_panel_fill",
+            "hub edges over the hub/tail engine's panel slots (0 once the "
+            "graph has left the hub/tail engine)",
+            ("graph",))
 
     def set_graph_gauges(self, rg: "RegisteredGraph") -> None:
         self.epoch.labels(graph=rg.name).set(rg.epoch)
@@ -214,12 +224,13 @@ class _RegistryObs:
             if values[0] == rg.name:    # a rebuild may have switched class
                 inst.set(0.0)
         self.engine_info.labels(graph=rg.name, engine=current).set(1.0)
-        fill = getattr(rg.engine, "slot_fill", None)
-        if fill is not None:
-            self.slot_fill.labels(graph=rg.name).set(fill)
-        elif any(v[0] == rg.name for v, _ in self.slot_fill.children()):
-            # the table outgrew its bar and the graph moved to COO
-            self.slot_fill.labels(graph=rg.name).set(0.0)
+        for attr in ("slot_fill", "hub_edge_share", "panel_fill"):
+            gauge, value = getattr(self, attr), getattr(rg.engine, attr, None)
+            if value is not None:
+                gauge.labels(graph=rg.name).set(value)
+            elif any(v[0] == rg.name for v, _ in gauge.children()):
+                # a rebuild moved the graph to another engine
+                gauge.labels(graph=rg.name).set(0.0)
 
 
 class GraphRegistry:

@@ -169,3 +169,157 @@ class TestDegreePriorOracle:
                     HubTailEngine.from_graph(g)):
             pi = _pagerank(eng, g, p=p)
             assert float(jnp.abs(pi - p).sum()) <= 1e-3
+
+
+def _kronecker(scale, seed):
+    """The benchmark's Graph 500 draw (A, B, C = 0.57, 0.19, 0.19,
+    edgefactor 16), duplicates, self loops and isolated vertices kept in
+    the edge list, as the Graph the program builds from it."""
+    import importlib.util
+    from pathlib import Path
+    from repro.graph.structure import Graph
+    path = Path(__file__).resolve().parents[1] / "bench" / "graphs" / \
+        "kronecker.py"
+    spec = importlib.util.spec_from_file_location("bench_kronecker", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    n, u, v = mod.make(seed, scale, 16, 0.57, 0.19, 0.19)
+    assert np.any(u == v)                     # the draw holds self loops
+    return Graph.from_undirected_edges(n, u, v)
+
+
+def _isolated(g):
+    """Vertices whose one edge is the self loop the Graph gave them."""
+    return np.unique(g.src[g.src == g.dst])
+
+
+@pytest.fixture(scope="module")
+def kron12():
+    return _kronecker(12, seed=12)
+
+
+@pytest.fixture(scope="module")
+def kron17():
+    return _kronecker(17, seed=17)
+
+
+def _l1_vs_dense(g, p, dtype=jnp.float32):
+    """Per-column L1 of cpaa_fixed through a forced HubTailEngine at
+    (c, tol) = (0.85, 1e-6) against the float64 direct solve."""
+    from repro.core.pagerank import true_pagerank_dense
+    eng = HubTailEngine.from_graph(g, dtype=dtype)
+    assert eng.n_hubs > 0 and eng.hub_edge_share > 0.5
+    sched = make_schedule(0.85, 1e-6)
+    pi, _ = cpaa_fixed(eng, jnp.asarray(sched.coeffs, dtype),
+                       jnp.asarray(p, dtype), rounds=sched.rounds)
+    ref = true_pagerank_dense(g, 0.85, p=p)
+    return np.abs(np.asarray(pi, np.float64) - ref).sum(axis=0)
+
+
+# Uniform p: Formula 8's 24 rounds truncate the series by under 1e-6 in
+# L1, and float32 rounding over 24 rounds adds under another 1e-6 (the
+# f32 solve reads 2.5e-7 on this draw; a bf16 iterate reads 4.0e-3).
+GLOBAL_L1_TOL = 2e-6
+# One-vertex seeds: the truncation is larger on a seed of low degree,
+# 7.3e-6 on this draw's random seed of degree 12 (the same series reads
+# the same in float64), so the bar is 2e-5 (a bf16 iterate reads 5.4e-3
+# on the hub's column and 6.5e-3 on that seed's).
+SEEDED_L1_TOL = 2e-5
+
+
+def _seeded_p(g):
+    """[n, 4] one-hot columns: the largest hub, an isolated vertex and two
+    random vertices."""
+    rng = np.random.default_rng(3)
+    seeds = [int(np.argmax(g.deg)), int(_isolated(g)[0]),
+             *map(int, rng.choice(g.n, 2, replace=False))]
+    p = np.zeros((g.n, 4))
+    p[seeds, np.arange(4)] = 1.0
+    return p
+
+
+class TestKronecker:
+    """Hub/tail on the Graph 500 law against a float64 reference."""
+
+    def test_global_matches_dense_reference(self, kron12):
+        p = np.full(kron12.n, 1.0 / kron12.n)
+        assert float(_l1_vs_dense(kron12, p)) <= GLOBAL_L1_TOL
+
+    def test_seeded_columns_match_dense_reference(self, kron12):
+        err = _l1_vs_dense(kron12, _seeded_p(kron12))
+        assert err.shape == (4,)
+        assert np.all(err <= SEEDED_L1_TOL), err
+        # an isolated seed keeps all its mass through its self loop
+        assert err[1] <= 1e-7
+
+    def test_bf16_iterate_fails_the_tolerances(self, kron12):
+        """The tolerances tell float32 from one precision step below."""
+        p = np.full(kron12.n, 1.0 / kron12.n)
+        assert float(_l1_vs_dense(kron12, p, jnp.bfloat16)) > GLOBAL_L1_TOL
+        err = _l1_vs_dense(kron12, _seeded_p(kron12), jnp.bfloat16)
+        assert err.max() > SEEDED_L1_TOL
+
+    def test_mass_conserved_with_a_third_isolated(self, kron17):
+        g = kron17
+        assert _isolated(g).size >= 0.3 * g.n
+        eng = HubTailEngine.from_graph(g)
+        x = jnp.asarray(np.random.default_rng(2).random(g.n, np.float32))
+        y = eng.apply(x)
+        np.testing.assert_allclose(float(y.sum()), float(x.sum()),
+                                   rtol=1e-5)
+        iso = _isolated(g)
+        np.testing.assert_array_equal(np.asarray(y)[iso], np.asarray(x)[iso])
+
+    @pytest.mark.parametrize("batch", [1, 128])
+    def test_auto_picks_hub_tail_at_the_bar(self, kron17, batch,
+                                            monkeypatch):
+        """n = 2^17 = HUB_TAIL_MIN_N: the heuristic picks the split
+        without building any engine."""
+        from repro.core.engine import heuristic_mode
+        assert kron17.n == HUB_TAIL_MIN_N
+
+        def no_build(*a, **k):
+            raise AssertionError("heuristic_mode built an engine")
+        monkeypatch.setattr(HubTailEngine, "from_graph", no_build)
+        assert heuristic_mode(kron17, batch) == "hub_tail"
+
+
+class TestSplitGauges:
+    def test_gauges_follow_the_engine(self, kron12, monkeypatch):
+        """The registry exports the split's hub edge share and panel fill,
+        and zeroes both once a rebuild moves the graph off hub/tail."""
+        import repro.core.engine as engine_mod
+        from repro.obs.metrics import MetricsRegistry
+        from repro.serve import GraphRegistry
+        g = kron12
+        monkeypatch.setattr(engine_mod, "HUB_TAIL_MIN_N", g.n)
+        reg = GraphRegistry(engine="auto", update_mode="rebuild")
+        metrics = MetricsRegistry()
+        reg.bind_metrics(metrics)
+        eng = reg.register("g", g).engine
+        assert eng.name == "hub_tail"
+
+        def gauge(name):
+            return metrics.get(name).labels(graph="g").value
+        assert gauge("graph_engine_hub_edge_share") == \
+            pytest.approx(eng.hub_edge_share)
+        assert gauge("graph_engine_panel_fill") == \
+            pytest.approx(eng.panel_fill)
+        hub_edges = int(g.deg[g.deg >= eng.hub_min_deg].sum())
+        assert eng.hub_edge_share == pytest.approx(hub_edges / g.m)
+        assert eng.panel_fill == pytest.approx(
+            hub_edges / np.asarray(eng.panel_cols).size)
+        assert 0.0 < eng.panel_fill <= 1.0
+        # the bar moves above n: the next rebuild leaves hub/tail
+        monkeypatch.setattr(engine_mod, "HUB_TAIL_MIN_N", g.n + 1)
+        iso = _isolated(g)
+        reg.apply_updates("g", insert=[(int(iso[0]), int(iso[1]))])
+        assert reg.get("g").engine.name != "hub_tail"
+        assert gauge("graph_engine_hub_edge_share") == 0.0
+        assert gauge("graph_engine_panel_fill") == 0.0
+
+    def test_properties_unknown_after_a_pytree_round_trip(self, kron12):
+        eng = HubTailEngine.from_graph(kron12)
+        leaves, treedef = jax.tree_util.tree_flatten(eng)
+        eng2 = jax.tree_util.tree_unflatten(treedef, leaves)
+        assert eng2.hub_edge_share is None and eng2.panel_fill is None

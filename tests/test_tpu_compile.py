@@ -38,6 +38,9 @@ MESH_K = 6
 # chunglu-1m's hub/tail split at the default hub bar (deg >= 32, panels
 # of 32 columns): hubs, tail edges and hub panels
 CL_N, CL_HUBS, CL_TAIL, CL_PANELS = 1_000_000, 44_148, 4_364_154, 299_850
+# graph500-19's split (the Graph 500 Kronecker draw at scale 19, seed 0)
+G500_N, G500_HUBS, G500_TAIL, G500_PANELS = 524_288, 57_943, 2_184_373, \
+    450_926
 
 
 @pytest.fixture(scope="module")
@@ -111,12 +114,13 @@ def _fused_engine(spec):
                                               children)
 
 
-def _hub_tail_engine(spec):
+def _hub_tail_engine(spec, n=CL_N, hubs=CL_HUBS, tail=CL_TAIL,
+                     panels=CL_PANELS):
     i32 = jnp.int32
-    children = (spec((CL_N,), jnp.float32), spec((CL_TAIL,), i32),
-                spec((CL_TAIL,), i32), spec((CL_PANELS, 32), i32),
-                spec((CL_PANELS,), i32), spec((CL_HUBS,), i32))
-    return HubTailEngine.tree_unflatten((CL_N, 32, 32, "float32"), children)
+    children = (spec((n,), jnp.float32), spec((tail,), i32),
+                spec((tail,), i32), spec((panels, 32), i32),
+                spec((panels,), i32), spec((hubs,), i32))
+    return HubTailEngine.tree_unflatten((n, 32, 32, "float32"), children)
 
 
 @pytest.mark.parametrize("build, kernels", [(_fused_engine, True),
@@ -137,6 +141,18 @@ def test_serve_solve_compiles(spec, build, kernels):
     assert (_kernel_calls(compiled) > 0) == kernels
 
 
+def _op_names(text) -> dict[str, list[str]]:
+    """Op kind (gather, scatter-add, fusion) -> the op_name of each."""
+    ops = {}
+    op_name = re.compile(
+        r'= \S+ (gather|scatter-add|fusion)\(.*op_name="([^"]*)"')
+    for line in text.splitlines():
+        m = op_name.search(line)
+        if m:
+            ops.setdefault(m.group(1), []).append(m.group(2))
+    return ops
+
+
 def test_spmm_scopes_reach_the_compiled_program(spec):
     """The global solve at mesh-1m's size on COO: every scatter-add and
     every gather the chip's compiler emits keeps its named scope in the
@@ -149,14 +165,7 @@ def test_spmm_scopes_reach_the_compiled_program(spec):
     text = cpaa_fixed.lower(
         CooEngine(dg), spec((sched.rounds + 1,), f32), spec((MESH_N,), f32),
         rounds=sched.rounds).compile().as_text()
-    ops = {}
-    op_name = re.compile(
-        r'= \S+ (gather|scatter-add|fusion)\(.*op_name="([^"]*)"')
-    for line in text.splitlines():
-        m = op_name.search(line)
-        if m:
-            ops.setdefault(m.group(1), []).append(m.group(2))
-    fusions = ops.get("fusion", [])
+    fusions = _op_names(text).get("fusion", [])
     assert any(p.endswith("spmm.scatter/scatter-add") for p in fusions)
     assert any(p.endswith("spmm.gather/gather") for p in fusions)
     for p in fusions:
@@ -196,3 +205,26 @@ def test_ell_serve_solve_holds_no_scatter(spec):
         max_rounds=make_schedule(c, tol).rounds,
         chunk=default_chunk(c, tol), k=32).compile().as_text()
     _assert_gather_without_scatter(text)
+
+
+def test_hub_tail_halves_reach_the_compiled_program(spec):
+    """The global solve at graph500-19's split: every gather and
+    scatter-add the compiler emits sits under the half it belongs to,
+    hub_tail.panel or hub_tail.tail, with spmm.gather / spmm.scatter still
+    the innermost scope (what bench/program_trace.py reads)."""
+    f32 = jnp.float32
+    sched = make_schedule(0.85, 1e-6)
+    eng = _hub_tail_engine(spec, G500_N, G500_HUBS, G500_TAIL, G500_PANELS)
+    text = cpaa_fixed.lower(
+        eng, spec((sched.rounds + 1,), f32), spec((G500_N,), f32),
+        rounds=sched.rounds).compile().as_text()
+    paths = [p for names in _op_names(text).values() for p in names
+             if p.endswith(("/gather", "/scatter-add"))]
+    for p in paths:
+        assert "/hub_tail.panel/" in p or "/hub_tail.tail/" in p, p
+        kernel = "spmm.gather" if p.endswith("/gather") else "spmm.scatter"
+        assert p.rsplit("/", 2)[-2] == kernel, p
+    assert any(p.endswith("/hub_tail.tail/spmm.scatter/scatter-add")
+               for p in paths)
+    assert any(p.endswith("/hub_tail.panel/spmm.gather/gather")
+               for p in paths)
