@@ -13,10 +13,12 @@ not an algorithm choice, so it lives behind an `Engine`:
                          on a scale-free graph receive the majority of all
                          edges) get dense fixed-width column panels reduced
                          by contiguous gather + row-sum, while the low-degree
-                         tail stays on the COO/segment path. P is applied in
-                         factored form (xd = x * inv_deg once per round), so
-                         no per-edge weights are stored at all: ~4 bytes/edge
-                         on the hub side vs COO's 12.
+                         tail is one neighbour table per degree slice, over
+                         an internal vertex order in which every block of
+                         output rows is contiguous (no scatter into [n]).
+                         P is applied in factored form (xd = x * inv_deg
+                         once per round), so no per-edge weights are stored
+                         at all: ~4 bytes/edge vs COO's 12.
   * EllEngine          — scatter-free neighbour table for bounded-degree
                          graphs: each vertex's adjacency list packed into a
                          [K, n] int32 table (K = the largest degree), applied
@@ -227,31 +229,58 @@ class CooEngine:
         return cls(children[0])
 
 
+def _degree_slices(degs: np.ndarray, rows: np.ndarray,
+                   pad: float) -> list[tuple[int, int]]:
+    """Group rows by degree into (width, rows) slices. `degs` lists the
+    degrees present in descending order, `rows` how many rows have each; a
+    slice takes the next lower degree while its slots (width * rows) stay
+    within `pad` times its edges."""
+    out: list[list[int]] = []
+    for d, r in zip(degs.tolist(), rows.tolist()):
+        if out and out[-1][0] * (out[-1][1] + r) <= \
+                pad * (out[-1][2] + d * r):
+            out[-1][1] += r
+            out[-1][2] += d * r
+        else:
+            out.append([d, r, d * r])
+    return [(w, r) for w, r, _ in out]
+
+
 @jax.tree_util.register_pytree_node_class
 class HubTailEngine:
     """Degree-split SpMM engine for skewed (power-law) graphs at scale.
 
     On a scale-free graph the hubs — the top few percent of vertices by
-    degree — receive the majority of all edges, and segment_sum (a serial
-    scatter-add on the CPU backend) pays per-edge for every one of them.
-    The split sends each edge down the path its DESTINATION's degree earns:
+    degree — receive the majority of all edges. The split sends each edge
+    down the path its DESTINATION's degree earns, and neither path
+    scatters into [n]:
 
       * hub rows (deg >= hub_min_deg, degree-sorted descending) are packed
         into fixed-width int32 column panels `panel_cols` [P, W]; a round
         gathers the panel columns contiguously, row-sums in the solve dtype,
         and reduces the per-panel partials with one tiny segment_sum over
         [P] -> [H] (P ~ hub_edges / W);
-      * tail rows keep the proven gather + segment_sum over the remaining
-        COO edges.
+      * tail rows are grouped by in-degree into slices, each a slot-major
+        neighbour table `tail_tables[s]` [w_s, n_s] as in EllEngine: the
+        slice's rows are the sum over its w_s slots of gathered rows of xd.
+        Adjacent degrees share a slice while its slots stay within
+        TAIL_SLICE_PAD of its edges. Tail rows whose one in-edge is their
+        own self loop (the isolated vertices `Graph` gives a loop) are a
+        plain copy of xd, and rows with no in-edge are zero.
+
+    The INTERNAL ORDER makes every output block contiguous: hubs in rank
+    order, then the sliced tail rows by in-degree descending, then the
+    self-loop rows, then the rows with no in-edge. `apply` writes y by
+    concatenating the blocks; `to_internal` / `from_internal` are the
+    permutation `perm` and its inverse (no padding rows), and every stored
+    index is an internal id.
 
     P = A D^{-1} is applied in FACTORED form: xd = x * inv_deg is computed
-    once per round (O(n)), so neither path stores per-edge weights — the
-    hub side costs ~4 bytes/edge (one int32 column id) and the tail 8,
-    against COO's 12 (f32 weights) or 10 (bf16). Panel padding slots hold
-    the sentinel column id n, which indexes a zero row appended to xd
-    inside `apply` — padding contributes exactly 0.0, preserving the mass
-    invariant, and the internal layout itself is the identity (original
-    vertex order, no padding rows).
+    once per round (O(n)), so neither path stores per-edge weights — about
+    4 bytes an edge (one int32 id), against COO's 12 (f32 weights) or 10
+    (bf16). Padding slots hold the sentinel id n, which indexes a zero row
+    appended to xd inside `apply` — padding contributes exactly 0.0,
+    preserving the mass invariant.
 
     `weight_dtype` packs inv_deg (bf16 halves it; upcast to the solve dtype
     before the multiply, so accumulation stays full precision).
@@ -260,32 +289,42 @@ class HubTailEngine:
     name = "hub_tail"
     DEFAULT_MIN_DEG = 32    # hub bar: deg >= 32 captures ~2/3 of the edges
     DEFAULT_PANEL_WIDTH = 32  # columns per panel: pad waste vs reduce count
+    # a tail slice's slots over its edges: merging adjacent degrees bounds
+    # the op count (w_s gathers a slice at B > 1) for under 5% padding
+    TAIL_SLICE_PAD = 1.05
 
-    # jaxlint: disable=JL004 -- hub_edges is a build statistic, deliberately not pytree state
-    def __init__(self, inv_deg: jax.Array, tail_src: jax.Array,
-                 tail_dst: jax.Array, panel_cols: jax.Array,
-                 panel_hub: jax.Array, hub_ids: jax.Array, n_orig: int,
-                 hub_min_deg: int, panel_width: int, acc_dtype=jnp.float32,
-                 hub_edges: int | None = None):
-        self.inv_deg = inv_deg         # [n] weight_dtype (packed ok)
-        self.tail_src = tail_src       # [m_tail] int32
-        self.tail_dst = tail_dst       # [m_tail] int32
+    # jaxlint: disable=JL004 -- hub_edges / tail_edges are build statistics, deliberately not pytree state
+    def __init__(self, inv_deg: jax.Array, perm: jax.Array,
+                 inv_perm: jax.Array, panel_cols: jax.Array,
+                 panel_hub: jax.Array, tail_tables: tuple, n_orig: int,
+                 n_hubs: int, n_copy: int, hub_min_deg: int,
+                 panel_width: int, acc_dtype=jnp.float32,
+                 hub_edges: int | None = None,
+                 tail_edges: int | None = None):
+        self.inv_deg = inv_deg         # [n] weight_dtype, internal order
+        self.perm = perm               # [n] int32 vertex id at each row
+        self.inv_perm = inv_perm       # [n] int32 row of each vertex id
         self.panel_cols = panel_cols   # [P, W] int32, sentinel n = padding
-        self.panel_hub = panel_hub     # [P] int32 hub rank of each panel
-        self.hub_ids = hub_ids         # [H] int32 vertex id per hub rank
+        self.panel_hub = panel_hub     # [P] int32 hub row of each panel
+        self.tail_tables = tuple(tail_tables)  # [w_s, n_s] int32 each
         self.n_orig = n_orig
+        self.n_hubs = n_hubs
+        self.n_copy = n_copy           # self-loop rows after the slices
         self.hub_min_deg = hub_min_deg
         self.panel_width = panel_width
         self.acc_dtype = jnp.dtype(acc_dtype)
         self.hub_edges = hub_edges     # live panel slots; not a pytree aux
+        self.tail_edges = tail_edges   # edges into tail rows; likewise
 
     @classmethod
     def from_graph(cls, g: Graph, hub_min_deg: int | None = None,
                    panel_width: int | None = None, dtype=jnp.float32,
                    weight_dtype=None) -> "HubTailEngine":
-        """Host-side build: degree-sort the hubs, lexsort their edges by
-        (hub rank, src) for gather locality, pack into W-wide panels.
-        All vectorized numpy — O(m log m)."""
+        """Host-side build: degree-sort the hubs and the tail into the
+        internal order, sort the hub edges by (hub row, src) for gather
+        locality and pack them into W-wide panels, and pack the tail's
+        in-neighbour lists into one table a degree slice. All vectorized
+        numpy — O(m log m)."""
         from repro.graph.ops import check_int32_range
         check_int32_range(g.n, g.m, what="HubTailEngine")
         thr = cls.DEFAULT_MIN_DEG if hub_min_deg is None else int(hub_min_deg)
@@ -294,25 +333,32 @@ class HubTailEngine:
         wdtype = jnp.dtype(dtype) if weight_dtype is None \
             else jnp.dtype(weight_dtype)
         n = g.n
-        src = np.asarray(g.src)
-        dst = np.asarray(g.dst)
+        src = np.asarray(g.src, np.int64)
+        dst = np.asarray(g.dst, np.int64)
         deg = np.bincount(src, minlength=n)
         inv_deg = 1.0 / np.maximum(deg, 1)
 
+        # the internal order: hubs by degree, then the tail by in-degree
         hub_mask_v = deg >= thr
         hub_ids = np.flatnonzero(hub_mask_v)
-        hub_ids = hub_ids[np.argsort(-deg[hub_ids],
-                                     kind="stable")].astype(np.int32)
+        hub_ids = hub_ids[np.argsort(-deg[hub_ids], kind="stable")]
+        indeg = np.bincount(dst, minlength=n)
+        only_loop = (indeg == 1) & \
+            (np.bincount(dst[src == dst], minlength=n) == 1)
+        tail_v = ~hub_mask_v & (indeg > 0) & ~only_loop
+        sliced = np.flatnonzero(tail_v)
+        sliced = sliced[np.argsort(-indeg[sliced], kind="stable")]
+        copy = np.flatnonzero(~hub_mask_v & only_loop)
+        perm = np.concatenate([hub_ids, sliced, copy,
+                               np.flatnonzero(~hub_mask_v & (indeg == 0))])
+        pos = np.empty(n, np.int64)
+        pos[perm] = np.arange(n)
         H = int(hub_ids.size)
-        hub_rank = np.full(n, -1, np.int64)
-        hub_rank[hub_ids] = np.arange(H)
+
+        # (row, src) pairs sorted as one int64 key row * n + src
         is_hub = hub_mask_v[dst]
-        hsrc = src[is_hub]
-        hr = hub_rank[dst[is_hub]]
-        tail_src = np.ascontiguousarray(src[~is_hub])
-        tail_dst = np.ascontiguousarray(dst[~is_hub])
-        order = np.lexsort((hsrc, hr))
-        hsrc, hr = hsrc[order], hr[order]
+        key = np.sort(pos[dst[is_hub]] * n + pos[src[is_hub]])
+        hr, hsrc = np.divmod(key, n)
         hdeg = np.bincount(hr, minlength=H)
         panels_per_hub = np.maximum((hdeg + width - 1) // width, 1)
         n_panels = int(panels_per_hub.sum())
@@ -320,24 +366,45 @@ class HubTailEngine:
         panel_hub = np.repeat(np.arange(H, dtype=np.int32), panels_per_hub)
         panel_base = np.concatenate([[0], np.cumsum(panels_per_hub)[:-1]])
         starts = np.concatenate([[0], np.cumsum(hdeg)[:-1]])
-        pos = panel_base[hr] * width + (np.arange(hsrc.size) - starts[hr])
-        cols.ravel()[pos] = hsrc
-        return cls(inv_deg=jnp.asarray(inv_deg, wdtype),
-                   tail_src=jnp.asarray(tail_src),
-                   tail_dst=jnp.asarray(tail_dst),
+        pos_h = panel_base[hr] * width + (np.arange(hsrc.size) - starts[hr])
+        cols.ravel()[pos_h] = hsrc
+
+        # tail slices: row r's in-neighbours fill column r - lo of its
+        # slice's table, slot by slot, the rest the sentinel
+        te = tail_v[dst]
+        trow, tsrc = np.divmod(np.sort(pos[dst[te]] * n + pos[src[te]]), n)
+        trow -= H
+        cnt = indeg[sliced]
+        edge_at = np.concatenate([[0], np.cumsum(cnt)])
+        slot = np.arange(trow.size) - edge_at[trow]
+        degs, rows = np.unique(cnt, return_counts=True)
+        tables, lo = [], 0
+        for w, r in _degree_slices(degs[::-1], rows[::-1],
+                                   cls.TAIL_SLICE_PAD):
+            e0, e1 = edge_at[lo], edge_at[lo + r]
+            tab = np.full((w, r), n, np.int32)
+            tab[slot[e0:e1], trow[e0:e1] - lo] = tsrc[e0:e1]
+            tables.append(jnp.asarray(tab))
+            lo += r
+        return cls(inv_deg=jnp.asarray(inv_deg[perm], wdtype),
+                   perm=jnp.asarray(perm, jnp.int32),
+                   inv_perm=jnp.asarray(pos, jnp.int32),
                    panel_cols=jnp.asarray(cols),
                    panel_hub=jnp.asarray(panel_hub),
-                   hub_ids=jnp.asarray(hub_ids),
-                   n_orig=n, hub_min_deg=thr, panel_width=width,
-                   acc_dtype=dtype, hub_edges=int(hsrc.size))
+                   tail_tables=tuple(tables), n_orig=n, n_hubs=H,
+                   n_copy=int(copy.size), hub_min_deg=thr,
+                   panel_width=width, acc_dtype=dtype,
+                   hub_edges=int(hsrc.size),
+                   tail_edges=int(g.m - hsrc.size))
 
     @property
     def n(self) -> int:
         return self.n_orig
 
     @property
-    def n_hubs(self) -> int:
-        return self.hub_ids.shape[0]
+    def tail_slots(self) -> int:
+        """Table slots of the tail slices plus the copied self-loop rows."""
+        return sum(t.size for t in self.tail_tables) + self.n_copy
 
     @property
     def hub_edge_share(self) -> float | None:
@@ -345,8 +412,7 @@ class HubTailEngine:
         panels carry (None on an engine rebuilt from pytree leaves)."""
         if self.hub_edges is None:
             return None
-        return self.hub_edges / max(self.hub_edges + self.tail_src.shape[0],
-                                    1)
+        return self.hub_edges / max(self.hub_edges + self.tail_edges, 1)
 
     @property
     def panel_fill(self) -> float | None:
@@ -355,6 +421,15 @@ class HubTailEngine:
         if self.hub_edges is None:
             return None
         return self.hub_edges / max(self.panel_cols.size, 1)
+
+    @property
+    def tail_slot_fill(self) -> float | None:
+        """Tail edges over tail slots: the share of the tail's tables that
+        is real work, at least 1 / TAIL_SLICE_PAD (None as above; 1.0 with
+        no tail)."""
+        if self.tail_edges is None:
+            return None
+        return self.tail_edges / self.tail_slots if self.tail_slots else 1.0
 
     @property
     def dtype(self):
@@ -368,30 +443,42 @@ class HubTailEngine:
         return self.inv_deg.dtype
 
     def to_internal(self, x: jax.Array) -> jax.Array:
-        return x
+        with jax.named_scope("hub_tail.order"):
+            return x[self.perm]
 
     def from_internal(self, x: jax.Array) -> jax.Array:
-        return x
+        with jax.named_scope("hub_tail.order"):
+            return x[self.inv_perm]
 
     def apply(self, x: jax.Array) -> jax.Array:
         _count_apply(self.name, x)
-        # sentinel row n: panel padding gathers exactly 0.0
+        # sentinel row n: padding slots gather exactly 0.0
         xd = _scaled_with_sentinel(x, self.inv_deg)
+        acc = jnp.promote_types(x.dtype, jnp.float32)
+        blocks = []
         # the halves' scopes sit outside spmm.gather / spmm.scatter, so a
         # trace reads each op by kernel and, one level up, by half
-        with jax.named_scope("hub_tail.tail"):
-            with jax.named_scope("spmm.gather"):
-                tail = xd[self.tail_src]
-            with jax.named_scope("spmm.scatter"):
-                y = jax.ops.segment_sum(tail, self.tail_dst,
-                                        num_segments=self.n_orig)
         with jax.named_scope("hub_tail.panel"):
             with jax.named_scope("spmm.gather"):
                 part = xd[self.panel_cols].sum(axis=1)
             with jax.named_scope("spmm.scatter"):
-                hub_y = jax.ops.segment_sum(part, self.panel_hub,
-                                            num_segments=self.n_hubs)
-                return y.at[self.hub_ids].add(hub_y)
+                blocks.append(jax.ops.segment_sum(
+                    part, self.panel_hub, num_segments=self.n_hubs))
+        with jax.named_scope("hub_tail.tail"):
+            with jax.named_scope("spmm.gather"):
+                for tab in self.tail_tables:
+                    if x.ndim == 1:
+                        y = xd[tab].astype(acc).sum(axis=0)
+                    else:
+                        y = xd[tab[0]].astype(acc)
+                        for k in range(1, tab.shape[0]):
+                            y = y + xd[tab[k]].astype(acc)
+                    blocks.append(y.astype(x.dtype))
+            lo = self.n_hubs + sum(t.shape[1] for t in self.tail_tables)
+            blocks.append(xd[lo:lo + self.n_copy])
+        blocks.append(jnp.zeros((self.n - lo - self.n_copy,) + x.shape[1:],
+                                x.dtype))
+        return jnp.concatenate(blocks)
 
     def cheb_round(self, y, t, acc, ck):
         return _default_cheb_round(y, t, acc, ck)
@@ -399,27 +486,28 @@ class HubTailEngine:
     def refresh(self, g: Graph, delta=None, *, dg=None,
                 **kw) -> "HubTailEngine":
         """Rebuild for the updated graph with the same split knobs. An edge
-        delta can move vertices across the hub threshold, so the honest
-        refresh is a full (vectorized, host-side) rebuild — no incremental
-        patch path; the registry's padded DeviceGraph, if any, is not
-        consulted."""
+        delta can move vertices across the hub threshold and between tail
+        slices, so the honest refresh is a full (vectorized, host-side)
+        rebuild — no incremental patch path; the registry's padded
+        DeviceGraph, if any, is not consulted."""
         return type(self).from_graph(g, hub_min_deg=self.hub_min_deg,
                                      panel_width=self.panel_width,
                                      dtype=self.acc_dtype,
                                      weight_dtype=self.inv_deg.dtype)
 
     def tree_flatten(self):
-        children = (self.inv_deg, self.tail_src, self.tail_dst,
-                    self.panel_cols, self.panel_hub, self.hub_ids)
-        aux = (self.n_orig, self.hub_min_deg, self.panel_width,
-               str(self.acc_dtype))
+        children = (self.inv_deg, self.perm, self.inv_perm, self.panel_cols,
+                    self.panel_hub, self.tail_tables)
+        aux = (self.n_orig, self.n_hubs, self.n_copy, self.hub_min_deg,
+               self.panel_width, str(self.acc_dtype))
         return children, aux
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        n_orig, hub_min_deg, panel_width, acc_dtype = aux
-        return cls(*children, n_orig=n_orig, hub_min_deg=hub_min_deg,
-                   panel_width=panel_width, acc_dtype=jnp.dtype(acc_dtype))
+        n_orig, n_hubs, n_copy, hub_min_deg, panel_width, acc_dtype = aux
+        return cls(*children, n_orig=n_orig, n_hubs=n_hubs, n_copy=n_copy,
+                   hub_min_deg=hub_min_deg, panel_width=panel_width,
+                   acc_dtype=jnp.dtype(acc_dtype))
 
 
 @partial(jax.jit, donate_argnums=(0, 1))

@@ -214,6 +214,12 @@ class _RegistryObs:
             "hub edges over the hub/tail engine's panel slots (0 once the "
             "graph has left the hub/tail engine)",
             ("graph",))
+        self.tail_slot_fill = reg.gauge(
+            "graph_engine_tail_slot_fill",
+            "tail edges over the hub/tail engine's tail slots: its degree "
+            "slices' table slots and copied self-loop rows (0 once the "
+            "graph has left the hub/tail engine)",
+            ("graph",))
 
     def set_graph_gauges(self, rg: "RegisteredGraph") -> None:
         self.epoch.labels(graph=rg.name).set(rg.epoch)
@@ -224,7 +230,8 @@ class _RegistryObs:
             if values[0] == rg.name:    # a rebuild may have switched class
                 inst.set(0.0)
         self.engine_info.labels(graph=rg.name, engine=current).set(1.0)
-        for attr in ("slot_fill", "hub_edge_share", "panel_fill"):
+        for attr in ("slot_fill", "hub_edge_share", "panel_fill",
+                     "tail_slot_fill"):
             gauge, value = getattr(self, attr), getattr(rg.engine, attr, None)
             if value is not None:
                 gauge.labels(graph=rg.name).set(value)

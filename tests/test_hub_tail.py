@@ -1,6 +1,8 @@
 """HubTailEngine tests: parity vs COO, degenerate splits, auto-selection,
-packed bf16 weights, serving integration, and the Grolmusz degree-prior
-oracle at paper scale."""
+packed bf16 weights, serving integration, the Grolmusz degree-prior
+oracle at paper scale, and the sliced tail over the internal order."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -14,6 +16,7 @@ from repro.core.pagerank import cpaa_fixed, degree_prior
 from repro.graph import generators
 from repro.graph.datasets import chung_lu
 from repro.graph.ops import device_graph
+from repro.graph.structure import Graph
 
 
 def _pagerank(eng, g, rounds=None, p=None):
@@ -73,7 +76,7 @@ class TestParity:
     @pytest.mark.parametrize("hub_min_deg", [1, 10**9])
     def test_degenerate_splits(self, hub_min_deg):
         """All-hub (every vertex panelized) and no-hub (pure tail
-        segment_sum) are both just P — the split point is a perf knob,
+        slices) are both just P — the split point is a perf knob,
         never a correctness one."""
         g = generators.powerlaw_ba(2_000, m_attach=4, seed=2)
         ref = _pagerank(CooEngine(device_graph(g)), g)
@@ -264,7 +267,8 @@ class TestKronecker:
         assert _isolated(g).size >= 0.3 * g.n
         eng = HubTailEngine.from_graph(g)
         x = jnp.asarray(np.random.default_rng(2).random(g.n, np.float32))
-        y = eng.apply(x)
+        # apply works in the engine's internal order
+        y = eng.from_internal(eng.apply(eng.to_internal(x)))
         np.testing.assert_allclose(float(y.sum()), float(x.sum()),
                                    rtol=1e-5)
         iso = _isolated(g)
@@ -305,6 +309,8 @@ class TestSplitGauges:
             pytest.approx(eng.hub_edge_share)
         assert gauge("graph_engine_panel_fill") == \
             pytest.approx(eng.panel_fill)
+        assert gauge("graph_engine_tail_slot_fill") == \
+            pytest.approx(eng.tail_slot_fill)
         hub_edges = int(g.deg[g.deg >= eng.hub_min_deg].sum())
         assert eng.hub_edge_share == pytest.approx(hub_edges / g.m)
         assert eng.panel_fill == pytest.approx(
@@ -317,9 +323,154 @@ class TestSplitGauges:
         assert reg.get("g").engine.name != "hub_tail"
         assert gauge("graph_engine_hub_edge_share") == 0.0
         assert gauge("graph_engine_panel_fill") == 0.0
+        assert gauge("graph_engine_tail_slot_fill") == 0.0
 
     def test_properties_unknown_after_a_pytree_round_trip(self, kron12):
         eng = HubTailEngine.from_graph(kron12)
         leaves, treedef = jax.tree_util.tree_flatten(eng)
         eng2 = jax.tree_util.tree_unflatten(treedef, leaves)
         assert eng2.hub_edge_share is None and eng2.panel_fill is None
+        assert eng2.tail_slot_fill is None
+
+
+@pytest.fixture(scope="module")
+def small_skewed():
+    return chung_lu(3_000, avg_deg=16.0, seed=4)
+
+
+def _one_edge():
+    """40 vertices: one edge, the other 38 isolated (self-loop rows)."""
+    return Graph.from_undirected_edges(40, np.array([5]), np.array([29]))
+
+
+def _hub_seeds(g):
+    """[n, 4] one-hot columns: the largest hub and three random
+    vertices."""
+    rng = np.random.default_rng(5)
+    seeds = [int(np.argmax(g.deg)),
+             *map(int, rng.choice(g.n, 3, replace=False))]
+    p = np.zeros((g.n, 4))
+    p[seeds, np.arange(4)] = 1.0
+    return p
+
+
+def _against_coo_and_dense(eng, g, batch):
+    """(L1 to COO, L1 to the float64 direct solve) per column of
+    cpaa_fixed through `eng`, uniform p at B = 1, `_hub_seeds` at B = 4."""
+    from repro.core.pagerank import true_pagerank_dense
+    p = np.full(g.n, 1.0 / g.n) if batch == 1 else _hub_seeds(g)
+    pj = jnp.asarray(p, jnp.float32)
+    pi = np.asarray(_pagerank(eng, g, p=pj), np.float64)
+    coo = np.asarray(_pagerank(CooEngine(device_graph(g)), g, p=pj),
+                     np.float64)
+    ref = true_pagerank_dense(g, 0.85, p=p)
+    return np.abs(pi - coo).sum(axis=0), np.abs(pi - ref).sum(axis=0)
+
+
+# a scatter op in compiled HLO text, and its op_name
+SCATTER_LINE = re.compile(r"= \S+ scatter\(.*op_name=\"([^\"]*)\"")
+
+
+def _scatter_scopes(eng, batch, n):
+    sched = make_schedule(0.85, 1e-6)
+    p = jnp.ones((n,) if batch == 1 else (n, batch), jnp.float32)
+    text = cpaa_fixed.lower(eng, jnp.asarray(sched.coeffs, jnp.float32), p,
+                            rounds=sched.rounds).compile().as_text()
+    return SCATTER_LINE.findall(text), text
+
+
+class TestSlicedTail:
+    """The tail as degree-sliced neighbour tables over the engine's
+    internal order (hubs, tail by in-degree, self-loop rows)."""
+
+    @pytest.mark.parametrize("batch", [1, 4])
+    @pytest.mark.parametrize("graph", ["small_skewed", "kron12"])
+    def test_matches_coo_and_dense(self, graph, batch, request):
+        g = request.getfixturevalue(graph)
+        eng = HubTailEngine.from_graph(g)
+        assert eng.n_hubs > 0 and len(eng.tail_tables) > 1
+        to_coo, to_dense = _against_coo_and_dense(eng, g, batch)
+        assert np.all(to_coo <= 1e-5), to_coo
+        assert np.all(to_dense <= (GLOBAL_L1_TOL if batch == 1
+                                   else SEEDED_L1_TOL)), to_dense
+
+    @pytest.mark.parametrize("split", ["all_hubs", "no_hubs", "one_edge"])
+    def test_degenerate_splits_match_dense(self, split):
+        """Every row a hub, every row in the tail, and a graph of
+        self-loop rows around one edge: each is P at B = 1 and 4."""
+        if split == "one_edge":
+            g, thr = _one_edge(), None
+        else:
+            g = generators.powerlaw_ba(800, m_attach=4, seed=2)
+            thr = 1 if split == "all_hubs" else 10**9
+        eng = HubTailEngine.from_graph(g, hub_min_deg=thr)
+        assert eng.n_hubs == (g.n if split == "all_hubs" else 0)
+        if split == "all_hubs":
+            assert eng.tail_tables == () and eng.n_copy == 0
+        if split == "one_edge":
+            assert [t.shape for t in eng.tail_tables] == [(1, 2)]
+            assert eng.n_copy == g.n - 2
+        for batch in (1, 4):
+            to_coo, to_dense = _against_coo_and_dense(eng, g, batch)
+            assert np.all(to_coo <= 1e-5), (batch, to_coo)
+            assert np.all(to_dense <= SEEDED_L1_TOL), (batch, to_dense)
+
+    def test_internal_order_is_a_permutation(self, kron12):
+        eng = HubTailEngine.from_graph(kron12)
+        perm = np.asarray(eng.perm)
+        np.testing.assert_array_equal(np.sort(perm), np.arange(kron12.n))
+        np.testing.assert_array_equal(np.asarray(eng.inv_perm)[perm],
+                                      np.arange(kron12.n))
+        rng = np.random.default_rng(6)
+        for shape in ((kron12.n,), (kron12.n, 3)):
+            x = jnp.asarray(rng.random(shape, np.float32))
+            np.testing.assert_array_equal(
+                np.asarray(eng.from_internal(eng.to_internal(x))),
+                np.asarray(x))
+        # hubs first by degree, then the sliced tail by degree, then the
+        # self-loop rows
+        deg = kron12.deg[perm]
+        body = deg[:kron12.n - eng.n_copy]
+        assert np.all(body[1:] <= body[:-1])
+        iso = set(_isolated(kron12).tolist())
+        assert set(perm[kron12.n - eng.n_copy:].tolist()) == iso
+
+    @pytest.mark.parametrize("batch", [1, 4])
+    def test_mass_conserved_in_internal_order(self, kron12, batch):
+        eng = HubTailEngine.from_graph(kron12)
+        shape = (kron12.n,) if batch == 1 else (kron12.n, batch)
+        x = jnp.asarray(np.random.default_rng(7).random(shape, np.float32))
+        y = np.asarray(eng.apply(x))
+        assert y.shape == shape
+        np.testing.assert_allclose(y.sum(axis=0), np.asarray(x).sum(axis=0),
+                                   rtol=1e-5)
+        # the self-loop rows are a copy of their own input
+        tail = slice(kron12.n - eng.n_copy, kron12.n)
+        np.testing.assert_array_equal(y[tail], np.asarray(x)[tail])
+
+    @pytest.mark.parametrize("batch", [1, 8])
+    def test_no_scatter_under_the_tail(self, small_skewed, batch):
+        """The compiled solve scatters only in the panel reduce; the
+        pattern finds COO's segment_sum, the control."""
+        g = small_skewed
+        scopes, text = _scatter_scopes(HubTailEngine.from_graph(g), batch,
+                                       g.n)
+        assert scopes and all("/hub_tail.panel/spmm.scatter/" in s
+                              for s in scopes), scopes
+        assert "/hub_tail.tail/spmm.gather/gather" in text
+        coo, _ = _scatter_scopes(CooEngine(device_graph(g)), batch, g.n)
+        assert coo and all("/spmm.scatter/" in s for s in coo)
+
+    @pytest.mark.parametrize("graph", ["small_skewed", "kron12", "kron17"])
+    def test_tail_slot_fill(self, graph, request):
+        """Tail slots stay within 1.1 of the tail's edges; every edge into
+        a tail row holds one slot (or is a copied self loop)."""
+        g = request.getfixturevalue(graph)
+        eng = HubTailEngine.from_graph(g)
+        assert eng.tail_slot_fill >= 1 / 1.1
+        live = sum(int((np.asarray(t) != g.n).sum())
+                   for t in eng.tail_tables)
+        assert live + eng.n_copy == eng.tail_edges == g.m - eng.hub_edges
+        assert eng.tail_slot_fill == pytest.approx(
+            eng.tail_edges / (sum(t.size for t in eng.tail_tables)
+                              + eng.n_copy))

@@ -36,11 +36,22 @@ MESH_M = 6_231_842
 # its neighbour table: maximum degree 6, so [6, n] int32 slots
 MESH_K = 6
 # chunglu-1m's hub/tail split at the default hub bar (deg >= 32, panels
-# of 32 columns): hubs, tail edges and hub panels
-CL_N, CL_HUBS, CL_TAIL, CL_PANELS = 1_000_000, 44_148, 4_364_154, 299_850
+# of 32 columns): hubs, hub panels, self-loop rows and the tail's degree
+# slices as (table width, rows)
+CL_N, CL_HUBS, CL_PANELS, CL_COPY = 1_000_000, 44_148, 299_850, 86_110
+CL_SLICES = ((31, 4_757), (28, 5_893), (25, 7_540), (22, 6_467), (20, 7_568),
+             (18, 9_518), (16, 12_434), (14, 16_332), (12, 23_003),
+             (10, 15_208), (9, 19_374), (8, 24_876), (7, 32_864), (6, 45_487),
+             (5, 65_548), (4, 96_570), (3, 137_617), (2, 173_808),
+             (1, 164_878))
 # graph500-19's split (the Graph 500 Kronecker draw at scale 19, seed 0)
-G500_N, G500_HUBS, G500_TAIL, G500_PANELS = 524_288, 57_943, 2_184_373, \
-    450_926
+G500_N, G500_HUBS, G500_PANELS, G500_COPY = 524_288, 57_943, 450_926, \
+    188_967
+G500_SLICES = ((31, 10_524), (28, 10_955), (25, 8_401), (22, 4_482),
+               (19, 1_583), (17, 1_747), (15, 4_073), (13, 9_418),
+               (11, 7_344), (10, 8_934), (9, 10_186), (8, 10_544),
+               (7, 10_457), (6, 10_963), (5, 13_199), (4, 18_233),
+               (3, 26_503), (2, 39_949), (1, 69_883))
 
 
 @pytest.fixture(scope="module")
@@ -114,13 +125,14 @@ def _fused_engine(spec):
                                               children)
 
 
-def _hub_tail_engine(spec, n=CL_N, hubs=CL_HUBS, tail=CL_TAIL,
-                     panels=CL_PANELS):
+def _hub_tail_engine(spec, n=CL_N, hubs=CL_HUBS, panels=CL_PANELS,
+                     copy=CL_COPY, slices=CL_SLICES):
     i32 = jnp.int32
-    children = (spec((n,), jnp.float32), spec((tail,), i32),
-                spec((tail,), i32), spec((panels, 32), i32),
-                spec((panels,), i32), spec((hubs,), i32))
-    return HubTailEngine.tree_unflatten((n, 32, 32, "float32"), children)
+    children = (spec((n,), jnp.float32), spec((n,), i32), spec((n,), i32),
+                spec((panels, 32), i32), spec((panels,), i32),
+                tuple(spec(shape, i32) for shape in slices))
+    return HubTailEngine.tree_unflatten((n, hubs, copy, 32, 32, "float32"),
+                                        children)
 
 
 @pytest.mark.parametrize("build, kernels", [(_fused_engine, True),
@@ -211,20 +223,27 @@ def test_hub_tail_halves_reach_the_compiled_program(spec):
     """The global solve at graph500-19's split: every gather and
     scatter-add the compiler emits sits under the half it belongs to,
     hub_tail.panel or hub_tail.tail, with spmm.gather / spmm.scatter still
-    the innermost scope (what bench/program_trace.py reads)."""
+    the innermost scope (what bench/program_trace.py reads), or is one of
+    the internal order's permutation gathers under hub_tail.order. The
+    tail gathers its degree slices and scatters nothing."""
     f32 = jnp.float32
     sched = make_schedule(0.85, 1e-6)
-    eng = _hub_tail_engine(spec, G500_N, G500_HUBS, G500_TAIL, G500_PANELS)
+    eng = _hub_tail_engine(spec, G500_N, G500_HUBS, G500_PANELS, G500_COPY,
+                           G500_SLICES)
     text = cpaa_fixed.lower(
         eng, spec((sched.rounds + 1,), f32), spec((G500_N,), f32),
         rounds=sched.rounds).compile().as_text()
     paths = [p for names in _op_names(text).values() for p in names
              if p.endswith(("/gather", "/scatter-add"))]
     for p in paths:
+        if p.endswith("/hub_tail.order/gather"):
+            continue
         assert "/hub_tail.panel/" in p or "/hub_tail.tail/" in p, p
         kernel = "spmm.gather" if p.endswith("/gather") else "spmm.scatter"
         assert p.rsplit("/", 2)[-2] == kernel, p
-    assert any(p.endswith("/hub_tail.tail/spmm.scatter/scatter-add")
+    assert not any("/hub_tail.tail/" in p and p.endswith("/scatter-add")
+                   for p in paths)
+    assert any(p.endswith("/hub_tail.tail/spmm.gather/gather")
                for p in paths)
     assert any(p.endswith("/hub_tail.panel/spmm.gather/gather")
                for p in paths)
