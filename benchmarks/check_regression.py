@@ -1,18 +1,16 @@
 """Benchmark regression gate for CI.
 
-Compares the fresh `engine_compare`, `autotune_compare`,
-`adaptive_compare`, `update_churn`, `scale_compare`, `serve_pagerank` AND
-`load_bench` records of a `benchmarks.run --json` output against the
-committed baseline (BENCH_pagerank.json) and fails when any entry — keyed
-(family, B, engine) for engine_compare, (family, B, "tuned-selector") for
-autotune_compare (heuristic vs measured engine selection: the
-"tuned-tuned" keys gate the tuner's pick end to end), (family, B,
-"engine/mode") for adaptive_compare, (family, batch_edges, "update/mode")
-for update_churn (per-batch update latency, so update-path regressions
-gate like solve regressions), (family, B, "scale-engine/weight_dtype") for
-the paper-scale per-iteration times, (family, B, "serve/mean" |
-"serve/p99") for the serving section (the p99 key gates TAIL latency,
-which a mean can hide), and (family, B, "load-tenant/sched" |
+Compares the fresh `engine_compare`, `adaptive_compare`, `update_churn`,
+`scale_compare`, `serve_pagerank` AND `load_bench` records of a
+`benchmarks.run --json` output against the committed baseline
+(BENCH_pagerank.json) and fails when any entry — keyed (family, B, engine)
+for engine_compare, (family, B, "engine/mode") for adaptive_compare,
+(family, batch_edges, "update/mode") for update_churn (per-batch update
+latency, so update-path regressions gate like solve regressions),
+(family, B, "scale-engine/weight_dtype") for the paper-scale
+per-iteration times, (family, B, "serve/mean" | "serve/p99") for the
+serving section (the p99 key gates TAIL latency, which a mean can hide),
+and (family, B, "load-tenant/sched" |
 "goodput-tenant/sched") for the open-loop scheduling section (per-tenant
 p99 under bursty load, plus goodput-under-SLO inverted to
 us-per-good-query so lower is better) — slowed down by more than
@@ -63,11 +61,6 @@ def _load_payload(path: str) -> tuple[dict, dict[tuple, float]]:
     out = {}
     for rec in payload.get("engine_compare", []):
         out[(rec["family"], rec["B"], rec["engine"])] = rec["us_per_solve"]
-    for rec in payload.get("autotune_compare", []):
-        # "tuned-auto" is the heuristic pick timed by the autotune bench,
-        # "tuned-tuned" the measured pick — disjoint from engine_compare
-        out[(rec["family"], rec["B"],
-             f"tuned-{rec['selector']}")] = rec["us_per_solve"]
     for rec in payload.get("adaptive_compare", []):
         # "engine/mode" keeps these keys disjoint from engine_compare's
         out[(rec["family"], rec["B"],

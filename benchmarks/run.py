@@ -32,9 +32,9 @@ def main(argv=None) -> None:
     quick = args.quick
 
     import jax
-    from benchmarks import (adaptive_bench, autotune_bench, engine_bench,
-                            kernels_bench, load_bench, paper_tables,
-                            scale_bench, serve_pagerank_bench, sharded_bench,
+    from benchmarks import (adaptive_bench, engine_bench, kernels_bench,
+                            load_bench, paper_tables, scale_bench,
+                            serve_pagerank_bench, sharded_bench,
                             update_churn_bench)
 
     sections: dict[str, list] = {}
@@ -49,13 +49,6 @@ def main(argv=None) -> None:
     # section CI tracks from every push
     eng_rows, eng_records = engine_bench.engine_compare(quick=quick)
     _emit(sections, "engine_compare_cpaa_end_to_end", eng_rows)
-
-    # measured vs heuristic engine selection: mode="tuned" must match
-    # mode="auto" up to jitter everywhere and beat it where the constants
-    # mis-pick (powerlaw); the tuner's store rides the CI actions/cache so
-    # warm runs perform zero tuning solves — runs in BOTH modes
-    at_rows, at_records = autotune_bench.autotune_compare(quick=quick)
-    _emit(sections, "autotune_compare_heuristic_vs_tuned", at_rows)
 
     # adaptive (residual-controlled) vs fixed-round CPAA: rounds saved +
     # wall-clock, also tracked by the regression gate from every push
@@ -127,7 +120,6 @@ def main(argv=None) -> None:
                 "jax": jax.__version__,
             },
             "engine_compare": eng_records,
-            "autotune_compare": at_records,
             "adaptive_compare": ad_records,
             "sharded_compare": sh_records,
             "update_churn": uc_records,

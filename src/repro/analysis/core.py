@@ -98,9 +98,6 @@ class LintConfig:
     blocking_allowed: tuple[tuple[str, str], ...] = (
         ("obs/trace.py", "*"),
         ("serve/pagerank_service.py", "_harvest"),
-        # the autotuner's candidate timing is a deliberate fence: it times
-        # warm solve rounds, so every rep must be device-complete
-        ("core/autotune.py", "_time_round"),
     )
     blocking_calls: tuple[str, ...] = ("block_until_ready", "device_get",
                                        "effects_barrier")

@@ -33,12 +33,12 @@ C = 0.85
 TOL = 1e-3
 LANE = 128
 IMBALANCE = 1.15   # per-device edge-count padding factor
-# solve-engine format ("auto" | "tuned" | "coo" | "block_ell" | "fused" |
-# "sharded-1d" | "sharded-2d"); the distributed dry-run cells build their
-# partition from the SHAPES table regardless, but smoke_run and local solves
-# route through core/engine.select_engine — "auto" shards when the process
-# has >= 2 devices and the graph clears the collective-amortization bar;
-# "tuned" consults the core/autotune measured-selection store instead.
+# solve-engine format ("auto" | "coo" | "hub-tail" | "ell" | "block_ell" |
+# "fused" | "sharded-1d" | "sharded-2d"); the distributed dry-run cells build
+# their partition from the SHAPES table regardless, but smoke_run and local
+# solves route through core/engine.select_engine — "auto" shards when the
+# process has >= 2 devices and the graph clears the collective-amortization
+# bar.
 ENGINE = "auto"
 # sharded-engine mesh knobs for smoke_run/local solves: (R, C) grid for
 # sharded-2d (None = most-square factorization of the device count) and the

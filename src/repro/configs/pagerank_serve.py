@@ -28,19 +28,11 @@ class PPRServeConfig:
     max_batch: int = 32
     cache_capacity: int = 4096
     max_top_k: int = 16
-    # solve-engine format: "auto" (device-count + degree-skew + fill-rate
-    # heuristic), "tuned" (measured selection via core/autotune: the
-    # workload-bucketed tuning store, measure-on-miss), "coo", "hub-tail",
-    # "ell", "block_ell", "fused", "sharded-1d" or "sharded-2d" — see
+    # solve-engine format: "auto" (the device-count + degree-skew +
+    # fill-rate selection) or a forced "coo", "hub-tail", "ell",
+    # "block_ell", "fused", "sharded-1d" or "sharded-2d" — see
     # core/engine.select_engine and docs/performance.md
     engine: str = "auto"
-    # tuned mode only: tuning-store path (None = $REPRO_TUNE_CACHE or
-    # ~/.cache/repro_pagerank/tuning.json), per-graph measurement budget in
-    # seconds, and whether a store miss falls back to the heuristic instead
-    # of measuring (require_cached — for latency-critical starts)
-    tune_cache: str | None = None
-    tune_budget_s: float = 2.0
-    tune_require_cached: bool = False
     # packed storage dtype for edge weights / inv_deg ("bfloat16" halves
     # them; accumulation stays f32). None = solve dtype. Parity bound:
     # L1 <= ~1e-3 on normalized PageRank (the one 1/deg rounding).
@@ -130,10 +122,7 @@ def make_service(cfg: PPRServeConfig, graphs=None):
                         update_mode=cfg.update_mode,
                         weight_dtype=None if cfg.weight_dtype is None
                         else jnp.dtype(cfg.weight_dtype),
-                        ingest_chunk_edges=cfg.ingest_chunk_edges,
-                        tune_cache=cfg.tune_cache,
-                        tune_budget_s=cfg.tune_budget_s,
-                        tune_require_cached=cfg.tune_require_cached)
+                        ingest_chunk_edges=cfg.ingest_chunk_edges)
     if graphs is None:
         graphs = {name: generators.paper_dataset(dataset, scale)
                   for name, dataset, scale in cfg.graphs}

@@ -13,14 +13,6 @@ from repro.core.chebyshev import (
     rounds_for_tolerance,
     sigma_c,
 )
-from repro.core.autotune import (
-    Autotuner,
-    TuningStore,
-    WorkloadKey,
-    default_tuner,
-    graph_fingerprint,
-    pick_winner,
-)
 from repro.core.engine import (
     BlockEllEngine,
     CooEngine,
@@ -55,6 +47,4 @@ __all__ = [
     "CooEngine", "BlockEllEngine", "FusedBlockEllEngine", "ShardedEngine",
     "Sharded1DEngine", "Sharded2DEngine", "as_engine", "factor_grid",
     "heuristic_mode", "select_engine",
-    "Autotuner", "TuningStore", "WorkloadKey", "default_tuner",
-    "graph_fingerprint", "pick_winner",
 ]

@@ -72,18 +72,21 @@ set is small (skipping the dominant host-side BFS); the sharded engines
 rebuild their partition on the SAME mesh. `delta` is a `graph.structure.EdgeDelta` (or None to force the
 conservative rebuild).
 
-`select_engine(g, batch)` picks a format host-side: with multiple devices
-and a graph big enough to amortize the per-round collectives it shards
-(2D grid when the mesh has >= 4 devices and n clears the 2D bar, 1D row
-otherwise); on a single device it picks by tile fill-rate — block-ELL pays
-off when the BxB tiles are dense enough that the dense-tile flops beat the
-gather/scatter traffic of segment_sum (community and mesh-like graphs);
-below the fill bar, graphs whose neighbour table stays small next to the
-edge count (bounded degree: meshes) take the ELL gather, and the rest stay
-on COO.
+`select_engine(g, batch)` picks a format host-side, in `_auto_choice`'s
+order: with multiple devices and a graph big enough to amortize the
+per-round collectives it shards (2D grid when the mesh has >= 4 devices
+and n clears the 2D bar, 1D row otherwise); on a single device a large
+graph whose hubs receive most of the edges takes the hub/tail split; a
+graph too small to tile stays on COO; the rest go by the tile fill-rate
+probe (memoized per graph fingerprint and block) — the fused block-ELL
+engine when the BxB tiles are dense enough that the dense-tile flops beat
+the gather/scatter traffic of segment_sum, else the ELL gather when the
+neighbour table stays small next to the edge count (bounded degree:
+meshes), else COO.
 """
 from __future__ import annotations
 
+import hashlib
 import math
 from functools import partial
 
@@ -122,8 +125,8 @@ __all__ = [
     "reset_apply_counts",
 ]
 
-ENGINE_MODES = ("auto", "tuned", "coo", "hub_tail", "ell", "block_ell",
-                "fused", "sharded_1d", "sharded_2d")
+ENGINE_MODES = ("auto", "coo", "hub_tail", "ell", "block_ell", "fused",
+                "sharded_1d", "sharded_2d")
 
 # Per-engine-class apply() invocation counts. apply() runs at TRACE time
 # under jit, so in a jitted serving loop these count COMPILATIONS of the
@@ -1154,9 +1157,10 @@ class Sharded2DEngine(ShardedEngine):
                    n_pad=n_pad, rows_per_chunk=rows, comm_dtype=comm_dtype)
 
 
-def as_engine(obj) -> CooEngine | BlockEllEngine:
-    """Coerce a DeviceGraph (the historical solver argument) to an engine;
-    pass engines through unchanged."""
+def as_engine(obj) -> (CooEngine | HubTailEngine | EllEngine |
+                        BlockEllEngine | ShardedEngine):
+    """Coerce a DeviceGraph (the historical solver argument) to a
+    CooEngine; pass any engine through unchanged."""
     if isinstance(obj, DeviceGraph):
         return CooEngine(obj)
     if hasattr(obj, "apply") and hasattr(obj, "to_internal"):
@@ -1234,14 +1238,33 @@ def _ell_fits(g: Graph, batch: int | None = None) -> bool:
         (limit is None or temp <= ELL_MAX_TEMP_SHARE * limit)
 
 
+def _graph_fingerprint(g: Graph, max_edges: int = 1 << 16) -> str:
+    """Content hash of a graph for the fill memo: (n, m) exactly, plus the
+    edge arrays (strided down to <= max_edges samples above that — a
+    collision then needs identical n, m AND identical sampled edges, and
+    the consequence of one is only a suboptimal format pick, never a wrong
+    result)."""
+    h = hashlib.sha1()
+    h.update(np.asarray([g.n, g.m], np.int64).tobytes())
+    stride = max(1, int(g.m) // max_edges)
+    h.update(np.ascontiguousarray(np.asarray(g.src)[::stride]).tobytes())
+    h.update(np.ascontiguousarray(np.asarray(g.dst)[::stride]).tobytes())
+    return h.hexdigest()[:16]
+
+
+# `block_fill_rate` results by (graph fingerprint, block), for the life of
+# the process: a graph whose edges were probed once skips the host BFS +
+# tile census on every later selection
+_FILL_MEMO: dict[tuple[str, int], float] = {}
+
+
 def _auto_choice(g: Graph, batch: int | None = None, *, block: int = 128,
                  min_fill: float | None = None, mesh: Mesh | None = None,
-                 sharded_min_n: int | None = None, probe_cache=None):
+                 sharded_min_n: int | None = None):
     """The zero-cost heuristic's decision, WITHOUT building anything:
     (mode, perm), where perm is the BFS permutation when the fill probe ran
-    fresh (the block-ELL build reuses it; None on a probe-cache hit or a
-    non-tiled pick). `probe_cache` is any object with
-    get_fill(g, block) / put_fill(g, block, fill) — see core.autotune."""
+    fresh (the block-ELL build reuses it; None on a memo hit or a
+    non-tiled pick)."""
     # multi-device: shard when the graph is large enough that the
     # per-device row work dominates the per-round collective (1D moves ~n
     # floats/device/round; 2D ~n/R + n/C, but needs a still-larger n to
@@ -1269,16 +1292,14 @@ def _auto_choice(g: Graph, batch: int | None = None, *, block: int = 128,
     # probe the tiling fill WITHOUT materializing tile values — scattered
     # graphs (the ones that fail the threshold) are exactly where the
     # [n_rb, S, B, B] tensor would be largest, and this runs on every
-    # serving epoch bump; the probe cache remembers the fill per (graph
-    # fingerprint, block) so re-probes of an already-seen shape skip the
-    # host BFS + tile census entirely
-    fill = perm = None
-    if probe_cache is not None:
-        fill = probe_cache.get_fill(g, block)
+    # serving epoch bump; the memo keeps the fill per (graph fingerprint,
+    # block) so re-probes of an already-seen shape skip the host BFS + tile
+    # census entirely
+    key = (_graph_fingerprint(g), int(block))
+    fill, perm = _FILL_MEMO.get(key), None
     if fill is None:
         fill, perm = block_fill_rate(g, block=block)
-        if probe_cache is not None:
-            probe_cache.put_fill(g, block, fill)
+        _FILL_MEMO[key] = float(fill)
     threshold = _default_min_fill() if min_fill is None else min_fill
     if fill < threshold:
         return ("ell" if _ell_fits(g, batch) else "coo"), None
@@ -1287,13 +1308,11 @@ def _auto_choice(g: Graph, batch: int | None = None, *, block: int = 128,
 
 def heuristic_mode(g: Graph, batch: int | None = None, *, block: int = 128,
                    min_fill: float | None = None, mesh: Mesh | None = None,
-                   sharded_min_n: int | None = None, probe_cache=None) -> str:
+                   sharded_min_n: int | None = None) -> str:
     """What `select_engine(mode="auto")` would pick for (g, batch), as a
-    concrete mode string, without building the engine — the zero-cost tier
-    the autotuner measures against (and ties back toward)."""
+    concrete mode string, without building the engine."""
     return _auto_choice(g, batch, block=block, min_fill=min_fill, mesh=mesh,
-                        sharded_min_n=sharded_min_n,
-                        probe_cache=probe_cache)[0]
+                        sharded_min_n=sharded_min_n)[0]
 
 
 def select_engine(g: Graph, batch: int | None = None, mode: str = "auto", *,
@@ -1303,7 +1322,7 @@ def select_engine(g: Graph, batch: int | None = None, mode: str = "auto", *,
                   stable_shapes: bool = False, mesh: Mesh | None = None,
                   grid: tuple[int, int] | None = None, lane: int = 128,
                   comm_dtype=None, sharded_min_n: int | None = None,
-                  weight_dtype=None, tuner=None, probe_cache=None):
+                  weight_dtype=None):
     """Pick/build the solve engine for a graph (host-side, once per epoch).
 
     mode: "coo" | "hub_tail" | "ell" | "block_ell" | "fused" | "sharded_1d"
@@ -1317,28 +1336,22 @@ def select_engine(g: Graph, batch: int | None = None, mode: str = "auto", *,
     fill-rate choice: block-ELL when its tile fill-rate clears `min_fill`
     (dense-enough tiles to beat segment_sum), otherwise the ELL gather when
     the neighbour table is at most ELL_MAX_SLOTS_PER_EDGE slots per edge
-    (bounded degree), else COO. "tuned"
-    replaces the guess with a measurement: the workload-bucketed autotuner
-    (core.autotune) consults its persistent store and, on a miss, times
-    the feasible candidates and picks the measured winner (tie-break
-    toward the heuristic's choice).
+    (bounded degree), else COO. The fill probe is memoized per (graph
+    fingerprint, block) for the life of the process.
     batch: expected personalization width (auto mode nudges tiny batches on
     small graphs back to COO; the MXU win needs columns to amortize the
     tiling round-trip).
     dg: reuse an existing DeviceGraph for the COO path (the serving registry
     passes its padded, shape-stable device graph).
-    stable_shapes: pad the ELL slot count to a multiple of
-    `BlockEllEngine.SLOT_QUANTUM` so edge updates rarely change jit shapes.
+    stable_shapes: pad the block-ELL slot count to a multiple of
+    `BlockEllEngine.SLOT_QUANTUM` so edge updates rarely change jit shapes
+    (the other engines ignore it).
     mesh / grid / lane / comm_dtype: sharded-engine knobs — an explicit mesh
     to run on (default: all devices), the (R, C) grid for sharded_2d, the
     partition padding lane, and an optional wire dtype for the all-gather.
     weight_dtype: packed storage dtype for edge weights / inv_deg on the
     COO, hub-tail and ELL paths (bf16 halves them; accumulation stays in
     `dtype`). The tile/partition engines ignore it (f32 values).
-    tuner: the `core.autotune.Autotuner` mode="tuned" consults (None = the
-    process-wide default over `$REPRO_TUNE_CACHE`).
-    probe_cache: fill-probe cache for auto mode (get_fill/put_fill; None =
-    probe every call — the serving registry threads the process cache).
     """
     mode = mode.replace("-", "_")
     if mode not in ENGINE_MODES:
@@ -1354,21 +1367,9 @@ def select_engine(g: Graph, batch: int | None = None, mode: str = "auto", *,
                                         weight_dtype=weight_dtype)
 
     perm = None
-    if mode == "tuned":
-        from repro.core.autotune import default_tuner  # lazy: no cycle
-        t = default_tuner() if tuner is None else tuner
-        dec = t.tune(g, batch=batch, dg=dg, dtype=dtype, block=block,
-                     min_fill=min_fill, use_kernel=use_kernel,
-                     interpret=interpret, stable_shapes=stable_shapes,
-                     mesh=mesh, grid=grid, lane=lane, comm_dtype=comm_dtype,
-                     sharded_min_n=sharded_min_n, weight_dtype=weight_dtype)
-        if dec.engine is not None:   # freshly measured winner: reuse as-is
-            return dec.engine
-        mode = dec.mode
     if mode == "auto":
         mode, perm = _auto_choice(g, batch, block=block, min_fill=min_fill,
-                                  mesh=mesh, sharded_min_n=sharded_min_n,
-                                  probe_cache=probe_cache)
+                                  mesh=mesh, sharded_min_n=sharded_min_n)
 
     if mode == "coo":
         return coo()

@@ -50,10 +50,6 @@ def serve_pagerank(mod, args):
         cfg = replace(cfg, max_batch=args.max_batch)
     if args.engine:
         cfg = replace(cfg, engine=args.engine)
-    if args.tune_cache:
-        cfg = replace(cfg, tune_cache=args.tune_cache)
-    if args.tune_budget is not None:
-        cfg = replace(cfg, tune_budget_s=args.tune_budget)
     if args.weight_dtype:
         cfg = replace(cfg, weight_dtype=None
                       if args.weight_dtype == "float32" else args.weight_dtype)
@@ -184,20 +180,11 @@ def main(argv=None):
     ap.add_argument("--updates", type=int, default=0,
                     help="edge-update batches interleaved (pagerank only)")
     ap.add_argument("--engine", default=None,
-                    choices=["auto", "tuned", "coo", "hub-tail", "ell",
+                    choices=["auto", "coo", "hub-tail", "ell",
                              "block_ell", "fused", "sharded-1d",
                              "sharded-2d"],
                     help="pagerank solve-engine override (default from "
-                         "config); 'tuned' selects by measurement via the "
-                         "persistent tuning store")
-    ap.add_argument("--tune-cache", default=None, metavar="PATH",
-                    help="tuning-store path for --engine tuned (default "
-                         "$REPRO_TUNE_CACHE or ~/.cache/repro_pagerank/"
-                         "tuning.json)")
-    ap.add_argument("--tune-budget", type=float, default=None,
-                    metavar="SECONDS",
-                    help="per-graph measurement budget for --engine tuned "
-                         "(default from config)")
+                         "config)")
     ap.add_argument("--weight-dtype", default=None,
                     choices=["float32", "bfloat16"],
                     help="packed edge-weight storage dtype (bfloat16 halves "

@@ -56,8 +56,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.core.autotune import (Autotuner, TuningStore, log2_bucket,
-                                 process_probe_cache)
 from repro.core.chebyshev import ChebSchedule, default_chunk, make_schedule
 from repro.core.engine import CooEngine, EllEngine, select_engine
 from repro.graph.ops import (DeviceGraph, EdgeSlots, device_graph,
@@ -116,15 +114,6 @@ class RegisteredGraph:
         self.last_delta: EdgeDelta | None = None
         self.last_update_incremental = False
         self._csr_cache = None
-        # tuned-mode state: the autotuner's winner for the current shape
-        # class, its measured per-round time (the serving layer seeds its
-        # solve-time estimator from it), and the log2 edge bucket the
-        # winner was tuned at — a rebuild re-tunes only when m leaves the
-        # bucket (the vertex set is fixed at registration, so n never
-        # moves). All None/0 outside engine="tuned".
-        self.tuned_mode: str | None = None
-        self.tune_us_per_iter: float | None = None
-        self.m_bucket = log2_bucket(host.m)
 
     @property
     def host(self) -> Graph:
@@ -246,9 +235,8 @@ class GraphRegistry:
     Args:
         dtype: accumulation dtype of device graphs and solves.
         engine: engine selection mode for `select_engine` ("auto" picks
-            COO / hub-tail / ELL / block-ELL / sharded per graph shape; "tuned"
-            consults the workload-bucketed autotuner — measured once per
-            (graph, shape class), persisted in the tuning store).
+            COO / hub-tail / ELL / block-ELL / sharded per graph shape; any
+            other mode forces that engine).
         batch_hint: expected micro-batch width, steering auto selection.
         mesh, grid, partition_lane: sharded-engine placement knobs.
         update_mode: "incremental" (in-place device patch when the batch
@@ -257,11 +245,6 @@ class GraphRegistry:
             (None = `dtype`); accumulation stays in `dtype`.
         ingest_chunk_edges: host->device transfer chunk at registration
             (None = one shot).
-        tune_cache: tuning-store path for engine="tuned" (None =
-            `$REPRO_TUNE_CACHE` / the user-cache default).
-        tune_budget_s: wall-clock cap per measurement pass.
-        tune_require_cached: never measure — a store miss falls back to
-            the heuristic (the zero-tuning-solves operating point).
 
     Invariant: `rg.engine` is always current for (graph, epoch) — every
     effective update refreshes or rebuilds it before the epoch bump
@@ -274,9 +257,7 @@ class GraphRegistry:
                  partition_lane: int = 128,
                  update_mode: str = "incremental",
                  weight_dtype=None,
-                 ingest_chunk_edges: int | None = None,
-                 tune_cache=None, tune_budget_s: float = 2.0,
-                 tune_require_cached: bool = False):
+                 ingest_chunk_edges: int | None = None):
         if update_mode not in UPDATE_MODES:
             raise ValueError(f"update_mode {update_mode!r} not in "
                              f"{UPDATE_MODES}")
@@ -297,18 +278,6 @@ class GraphRegistry:
         # host allocation at registration of paper-scale graphs (None = one
         # shot; see graph.ops._chunked_device_1d)
         self.ingest_chunk_edges = ingest_chunk_edges
-        # engine="tuned" owns an Autotuner whose store doubles as the
-        # fill-probe cache; every other mode shares the process-wide
-        # in-memory probe cache so epoch bumps on unchanged shapes skip the
-        # host BFS + tile census
-        self.tuner: Autotuner | None = None
-        if engine == "tuned":
-            self.tuner = Autotuner(TuningStore(tune_cache),
-                                   budget_s=tune_budget_s,
-                                   require_cached=tune_require_cached)
-            self._probe_cache = self.tuner.store
-        else:
-            self._probe_cache = process_probe_cache()
         self._graphs: dict[str, RegisteredGraph] = {}
         self._schedules: dict[tuple[float, float], tuple[ChebSchedule, jax.Array]] = {}
         self._adaptive: dict[tuple[float, float, int | None], AdaptiveSchedule] = {}
@@ -319,25 +288,17 @@ class GraphRegistry:
         (idempotent; called by PageRankService with its own). Gauges for
         already-registered graphs are published immediately."""
         self._obs = _RegistryObs(registry)
-        if self.tuner is not None:
-            self.tuner.bind_metrics(registry)
         for rg in self._graphs.values():
             self._obs.set_graph_gauges(rg)
 
-    def _build(self, g: Graph, name: str = "graph", rg=None):
-        """(DeviceGraph, engine, EdgeSlots, tuned_mode, tune_us_per_iter)
-        for one epoch of a graph. The COO engine reuses the padded device
-        graph; block-ELL engines pad their slot count so the solve keeps
-        stable jit shapes across epochs; sharded engines rebuild their mesh
-        partition here — per (graph, epoch), never on the tick path. The
-        EdgeSlots host mirror is what later updates patch through (None if
-        the graph breaks the symmetrized contract — those graphs always
-        rebuild).
-
-        In tuned mode the selection is measured once per (graph, shape
-        class): a rebuild whose edge count stays inside the previous log2
-        bucket reuses the prior winner (counted as a "sticky" decision),
-        anything else consults the tuner's store / measures afresh."""
+    def _build(self, g: Graph):
+        """(DeviceGraph, engine, EdgeSlots) for one epoch of a graph. The
+        COO engine reuses the padded device graph; block-ELL engines pad
+        their slot count so the solve keeps stable jit shapes across
+        epochs; sharded engines rebuild their mesh partition here — per
+        (graph, epoch), never on the tick path. The EdgeSlots host mirror
+        is what later updates patch through (None if the graph breaks the
+        symmetrized contract — those graphs always rebuild)."""
         try:
             slots = EdgeSlots.from_graph(g, cap=_edge_bucket(g.m))
         except ValueError:
@@ -348,23 +309,12 @@ class GraphRegistry:
             device_graph(g, self.dtype, pad_edges_to=_edge_bucket(g.m),
                          weight_dtype=self.weight_dtype,
                          chunk_edges=self.ingest_chunk_edges)
-        build_kw = dict(batch=self.batch_hint, dg=dg, dtype=self.dtype,
-                        stable_shapes=True, mesh=self.mesh, grid=self.grid,
-                        lane=self.partition_lane,
-                        weight_dtype=self.weight_dtype)
-        if self.tuner is None:
-            eng = select_engine(g, mode=self.engine_mode,
-                                probe_cache=self._probe_cache, **build_kw)
-            return dg, eng, slots, None, None
-        if rg is not None and rg.tuned_mode is not None and \
-                log2_bucket(g.m) == rg.m_bucket:
-            self.tuner.record("sticky", name, rg.tuned_mode)
-            eng = select_engine(g, mode=rg.tuned_mode, **build_kw)
-            return dg, eng, slots, rg.tuned_mode, rg.tune_us_per_iter
-        dec = self.tuner.tune(g, graph_name=name, **build_kw)
-        eng = dec.engine if dec.engine is not None else \
-            select_engine(g, mode=dec.mode, **build_kw)
-        return dg, eng, slots, dec.mode, dec.us_per_iter
+        eng = select_engine(g, batch=self.batch_hint, mode=self.engine_mode,
+                            dg=dg, dtype=self.dtype, stable_shapes=True,
+                            mesh=self.mesh, grid=self.grid,
+                            lane=self.partition_lane,
+                            weight_dtype=self.weight_dtype)
+        return dg, eng, slots
 
     # ---- graphs -----------------------------------------------------------
     def register(self, name: str, g: Graph) -> RegisteredGraph:
@@ -380,12 +330,11 @@ class GraphRegistry:
         if name in self._graphs:
             raise ValueError(f"graph {name!r} already registered")
         t0 = time.perf_counter()
-        dg, eng, slots, tuned_mode, tune_us = self._build(g, name)
+        dg, eng, slots = self._build(g)
         self._obs.build_seconds.labels(graph=name).observe(
             time.perf_counter() - t0)
         rg = RegisteredGraph(name=name, host=g, dg=dg, engine=eng,
                              keys=_undirected_keys(g), slots=slots)
-        rg.tuned_mode, rg.tune_us_per_iter = tuned_mode, tune_us
         self._graphs[name] = rg
         self._obs.set_graph_gauges(rg)
         return rg
@@ -468,11 +417,7 @@ class GraphRegistry:
             g_new = Graph.from_undirected_edges(n, keys // n, keys % n)
             rg.host = g_new
             t_build = time.perf_counter()
-            dg, eng, slots, tuned_mode, tune_us = self._build(g_new, name,
-                                                              rg=rg)
-            rg.dg, rg.engine, rg.slots = dg, eng, slots
-            rg.tuned_mode, rg.tune_us_per_iter = tuned_mode, tune_us
-            rg.m_bucket = log2_bucket(g_new.m)
+            rg.dg, rg.engine, rg.slots = self._build(g_new)
             self._obs.build_seconds.labels(graph=name).observe(
                 time.perf_counter() - t_build)
             rg.keys = keys

@@ -96,11 +96,6 @@ from repro.serve.scheduler import (AdmissionRejected, DeadlineScheduler,
 
 __all__ = ["PPRQuery", "PPRResult", "PageRankService", "ServeMetrics"]
 
-# Nominal round count used to scale the tuner's per-round measurement into
-# a whole-batch-solve seed for the deadline estimator (matches the round
-# budget the benchmarks time engines at).
-_SEED_ROUNDS = 12
-
 
 @dataclass(frozen=True)
 class PPRQuery:
@@ -515,23 +510,8 @@ class PageRankService:
         # the registry shares the service's metric registry (build/update/
         # BFS timings, per-graph gauges land next to the serve metrics)
         registry.bind_metrics(self.metrics.registry)
-        for gname in registry.names():
-            self._seed_estimator(gname)
         self._submitted = 0     # total accepted queries (qid autogeneration)
         self._tick_no = 0
-
-    def _seed_estimator(self, name: str) -> None:
-        """Prime the solve-time estimator from the tuner's measurement.
-
-        A tuned registry records us_per_iter for each graph; scaled by the
-        nominal round count it is a far better cold-start prior than the
-        estimator's default 0.0 (which makes the deadline scheduler
-        over-promise on the very first tick). No-op when untuned.
-        """
-        rg = self.registry.get(name)
-        us = getattr(rg, "tune_us_per_iter", None)
-        if us is not None:
-            self.estimator.seed(name, us * 1e-6 * _SEED_ROUNDS)
 
     @property
     def stats(self) -> dict:
@@ -702,10 +682,9 @@ class PageRankService:
         rg = self.registry.apply_updates(name, insert=insert, delete=delete)
         if type(rg.engine) is not prev_engine:
             # A rebuild picked a different engine: the old EWMAs time a
-            # layout that no longer runs, so deadline math must restart
-            # from the tuner's seed (or cold) rather than stale history.
+            # layout that no longer runs, so deadline math restarts cold
+            # rather than from stale history.
             self.estimator.reset(graph=name)
-            self._seed_estimator(name)
             m.engine_swaps.labels(graph=name).inc()
         delta = rg.last_delta
         edges_changed = (len(delta.inserted) + len(delta.deleted)

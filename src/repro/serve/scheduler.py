@@ -152,7 +152,6 @@ class SolveTimeEstimator:
         self._by_bucket: dict[tuple, float] = {}   # (graph, bucket) -> s
         self._by_graph: dict[str, float] = {}
         self._global: float | None = None
-        self._seeded: set[str] = set()   # graphs whose value is a tuner seed
 
     def _ewma(self, old: float | None, sample: float) -> float:
         return sample if old is None else \
@@ -168,14 +167,7 @@ class SolveTimeEstimator:
         """
         key = (graph, int(bucket))
         self._by_bucket[key] = self._ewma(self._by_bucket.get(key), seconds)
-        if graph in self._seeded:
-            # A tuner seed is a prior, not a sample: the first real
-            # observation replaces it outright instead of EWMA-blending.
-            self._seeded.discard(graph)
-            self._by_graph[graph] = seconds
-        else:
-            self._by_graph[graph] = self._ewma(self._by_graph.get(graph),
-                                               seconds)
+        self._by_graph[graph] = self._ewma(self._by_graph.get(graph), seconds)
         self._global = self._ewma(self._global, seconds)
 
     def estimate(self, graph: str, bucket: int) -> float:
@@ -196,38 +188,24 @@ class SolveTimeEstimator:
         """Copy of the per-(graph, bucket) EWMAs (for gauges / debugging)."""
         return dict(self._by_bucket)
 
-    def seed(self, graph: str, seconds: float) -> None:
-        """Install a prior for `graph` from an out-of-band measurement
-        (the engine autotuner's us_per_iter, scaled to a batch solve).
-
-        Only the per-graph fallback is seeded — bucket EWMAs stay empty so
-        exact samples still dominate — and only if nothing real has been
-        observed yet. The first `observe` for the graph replaces the seed.
-        """
-        if graph not in self._by_graph:
-            self._by_graph[graph] = float(seconds)
-            self._seeded.add(graph)
-
     def reset(self, graph: str | None = None) -> None:
         """Forget observations — everything, or one graph's.
 
         With no argument: full reset (benchmarks drop compile-polluted
         warm-up samples this way — the first solve at a shape pays the jit
         trace, which would otherwise dominate the EWMA for many ticks).
-        With `graph`: drop that graph's bucket EWMAs, graph fallback and
-        seed mark — the service does this on an engine swap so deadline
-        math never runs on the old engine's timings.
+        With `graph`: drop that graph's bucket EWMAs and graph fallback —
+        the service does this on an engine swap so deadline math never runs
+        on the old engine's timings.
         """
         if graph is None:
             self._by_bucket.clear()
             self._by_graph.clear()
             self._global = None
-            self._seeded.clear()
             return
         for key in [k for k in self._by_bucket if k[0] == graph]:
             del self._by_bucket[key]
         self._by_graph.pop(graph, None)
-        self._seeded.discard(graph)
 
 
 class FifoScheduler:

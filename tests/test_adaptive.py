@@ -19,7 +19,8 @@ import pytest
 from repro.core import (chunk_tail_ratio, cpaa, cpaa_adaptive,
                         cpaa_adaptive_fixed, default_chunk, make_schedule,
                         true_pagerank_dense)
-from repro.core.engine import BlockEllEngine, CooEngine, FusedBlockEllEngine
+from repro.core.engine import (BlockEllEngine, CooEngine, EllEngine,
+                               FusedBlockEllEngine, HubTailEngine)
 from repro.graph import generators
 from repro.graph.ops import device_graph
 
@@ -35,6 +36,10 @@ ENGINES = {
                                                      use_kernel=False),
     "fused": lambda g: FusedBlockEllEngine.from_graph(g, block=32,
                                                       use_kernel=False),
+    "ell": lambda g: EllEngine.from_graph(g),
+    # a hub bar of 6 leaves both halves non-empty on the graphs whose
+    # degrees allow it
+    "hub_tail": lambda g: HubTailEngine.from_graph(g, hub_min_deg=6),
 }
 
 TOL = 1e-6

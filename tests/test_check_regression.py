@@ -170,28 +170,3 @@ def test_backend_mismatch_refuses_to_compare(tmp_path):
                         str(pn), "--commit-msg", "routine"],
                        capture_output=True, text=True, timeout=60)
     assert r.returncode == 0, r.stdout
-
-
-def test_autotune_compare_entries_are_gated(tmp_path):
-    """autotune_compare records join the gate keyed (family, B,
-    tuned-selector) so the tuner's end-to-end pick gates like any other
-    solve timing."""
-    def payload(slow: float):
-        return {
-            "engine_compare": [{"family": "mesh", "B": 1, "engine": "coo",
-                                "us_per_solve": 50000.0}],
-            "autotune_compare": [
-                {"family": "powerlaw", "B": 8, "selector": "auto",
-                 "engine": "coo", "us_per_solve": 100000.0},
-                {"family": "powerlaw", "B": 8, "selector": "tuned",
-                 "engine": "hub_tail", "us_per_solve": 80000.0 * slow},
-            ],
-        }
-    po, pn = tmp_path / "o.json", tmp_path / "n.json"
-    po.write_text(json.dumps(payload(1.0)))
-    pn.write_text(json.dumps(payload(2.0)))  # tuned pick regressed 2x
-    r = subprocess.run([sys.executable, SCRIPT, "--old", str(po), "--new",
-                        str(pn), "--commit-msg", "routine"],
-                       capture_output=True, text=True, timeout=60)
-    assert r.returncode == 1, r.stdout
-    assert "tuned-tuned" in r.stdout

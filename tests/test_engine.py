@@ -12,8 +12,9 @@ import pytest
 
 from repro.core import (cpaa, cpaa_fixed, forward_push, make_schedule, power,
                         true_pagerank_dense)
-from repro.core.engine import (BlockEllEngine, CooEngine, FusedBlockEllEngine,
-                               as_engine, select_engine)
+from repro.core.engine import (BlockEllEngine, CooEngine, EllEngine,
+                               FusedBlockEllEngine, HubTailEngine, as_engine,
+                               heuristic_mode, select_engine)
 from repro.graph import generators
 from repro.graph.ops import device_graph, spmv
 
@@ -29,6 +30,10 @@ ENGINES = {
                                                      use_kernel=False),
     "fused": lambda g: FusedBlockEllEngine.from_graph(g, block=32,
                                                       use_kernel=False),
+    "ell": lambda g: EllEngine.from_graph(g),
+    # a hub bar of 6 leaves both halves non-empty on the graphs whose
+    # degrees allow it
+    "hub_tail": lambda g: HubTailEngine.from_graph(g, hub_min_deg=6),
 }
 
 
@@ -242,6 +247,47 @@ class TestSelection:
         dg = device_graph(g, pad_edges_to=2048)
         eng = select_engine(g, mode="coo", dg=dg)
         assert eng.dg is dg
+
+    def test_fingerprint_tracks_content(self):
+        from repro.core.engine import _graph_fingerprint
+        g1, g2 = generators.tri_mesh(10, 10), generators.tri_mesh(10, 11)
+        assert _graph_fingerprint(g1) == \
+            _graph_fingerprint(generators.tri_mesh(10, 10))
+        assert _graph_fingerprint(g1) != _graph_fingerprint(g2)
+
+    @staticmethod
+    def _count_probes(monkeypatch):
+        """Empty the fill memo and count `block_fill_rate` calls."""
+        import repro.core.engine as engine_mod
+        calls = []
+        real = engine_mod.block_fill_rate
+
+        def counting(g_, block=128, **kw):
+            calls.append(block)
+            return real(g_, block=block, **kw)
+
+        monkeypatch.setattr(engine_mod, "block_fill_rate", counting)
+        monkeypatch.setattr(engine_mod, "_FILL_MEMO", {})
+        return calls
+
+    def test_auto_mode_probes_once_per_shape(self, monkeypatch):
+        calls = self._count_probes(monkeypatch)
+        g = generators.caveman(30, 64, seed=0)  # dense tiles, n >= 2*block
+        m1 = heuristic_mode(g)
+        m2 = heuristic_mode(g)
+        assert m1 == m2
+        assert len(calls) == 1   # second call served from the fill memo
+
+    def test_auto_mode_uses_process_probe_cache(self, monkeypatch):
+        """Registries share the process-wide fill memo: a second
+        registration of the same graph, in another registry, skips the
+        probe."""
+        from repro.serve import GraphRegistry
+        calls = self._count_probes(monkeypatch)
+        g = generators.tri_mesh(20, 20)         # n >= 2*block: probed
+        GraphRegistry().register("a", g)
+        GraphRegistry().register("b", g)
+        assert len(calls) == 1
 
 
 class TestServeIntegration:

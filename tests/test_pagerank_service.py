@@ -249,6 +249,46 @@ class TestDynamicUpdates:
             _undirected_keys(svc.registry.get("g").host), keys0)
 
 
+class TestServiceEngineSwap:
+    def _service(self):
+        reg = GraphRegistry()
+        reg.register("g", generators.powerlaw_ba(1500, 6, seed=0))
+        return PageRankService(reg, max_batch=8, cache_capacity=16,
+                               max_top_k=4)
+
+    def test_engine_swap_resets_estimator(self, monkeypatch):
+        svc = self._service()
+        svc.estimator.observe("g", 8, 123.0)
+        rg = svc.registry.get("g")
+        real_apply = type(svc.registry).apply_updates
+
+        def swapping(self_reg, name, insert=(), delete=()):
+            out = real_apply(self_reg, name, insert=insert, delete=delete)
+            # force a different engine CLASS, as a rebuild whose graph
+            # crosses one of auto's bars would
+            from repro.core.engine import select_engine
+            out.engine = select_engine(out.host, mode="hub_tail")
+            return out
+
+        monkeypatch.setattr(type(svc.registry), "apply_updates", swapping)
+        assert type(rg.engine).__name__ == "CooEngine"
+        svc.update_graph("g", insert=[(0, 7)])
+        assert type(svc.registry.get("g").engine).__name__ == "HubTailEngine"
+        # stale per-(graph, bucket) and per-graph EWMAs for the old engine
+        # are gone (estimate may still fall back to the cross-graph global)
+        assert ("g", 8) not in svc.estimator.snapshot()
+        assert "g" not in svc.estimator._by_graph
+        swaps = svc.metrics.engine_swaps.labels(graph="g").value
+        assert swaps == 1
+
+    def test_no_swap_no_reset(self):
+        svc = self._service()
+        svc.estimator.observe("g", 8, 123.0)
+        svc.update_graph("g", insert=[(0, 7)])
+        assert svc.estimator.estimate("g", 8) == 123.0
+        assert svc.metrics.engine_swaps.labels(graph="g").value == 0
+
+
 class TestTopK:
     def test_topk_agrees_with_dense_oracle(self):
         g = generators.tri_mesh(8, 9)

@@ -50,6 +50,22 @@ class TestSolveTimeEstimator:
         assert est.estimate("g", 4) == 0.0
         assert est.snapshot() == {}
 
+    def test_reset_single_graph(self):
+        est = SolveTimeEstimator()
+        est.observe("a", 8, 0.1)
+        est.observe("b", 8, 0.4)
+        est.reset(graph="a")
+        # a falls through its cleared keys to the global EWMA; b keeps its
+        # exact bucket sample
+        assert est.estimate("b", 8) == 0.4
+        assert est.estimate("a", 8) == est._global
+
+    def test_reset_all_still_works(self):
+        est = SolveTimeEstimator(default_s=3.0)
+        est.observe("a", 8, 0.1)
+        est.reset()
+        assert est.estimate("a", 8) == 3.0
+
     def test_snapshot_is_a_copy(self):
         est = SolveTimeEstimator()
         est.observe("g", 4, 1.0)

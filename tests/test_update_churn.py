@@ -147,7 +147,7 @@ class TestDevicePatchRoundTrip:
         np.testing.assert_array_equal(es.deg, deg0)
 
 
-ENGINES = ["coo", "block_ell", "fused", "sharded-1d"]
+ENGINES = ["coo", "ell", "hub-tail", "block_ell", "fused", "sharded-1d"]
 
 
 class TestIncrementalVsRebuildParity:
